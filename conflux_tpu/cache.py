@@ -1,71 +1,64 @@
-"""Persistent XLA compilation cache, promoted from bench.py into the library.
+"""Persistent XLA compilation cache, shared by every entry point.
 
 The at-scale programs cost minutes of compile each (the N=32768 LU is
-4-6 min per config) and every process historically re-paid that cost:
-bench.py carried a private `_enable_compile_cache` while the CLIs, the
-serve layer, and the tuning scripts compiled from scratch. This module is
-the single switch-on point: the serve path (`conflux_tpu.serve`) and the
-miniapp CLIs call :func:`enable_persistent_cache` at startup so cold-start
-compiles amortize across processes — a second process hitting the same
-(geometry, knobs) config deserializes the executable in seconds.
+about a minute and a half per config on a v5e), so the serve path
+(`conflux_tpu.serve`), the miniapp CLIs, `bench.py` and `chip_smoke.py`
+all call :func:`enable_persistent_cache` at startup: a second process
+hitting the same (geometry, knobs) config deserializes the executable.
 
-The cache location resolves, in order:
+Where the cache lives:
 
-1. an explicit `path=` argument,
-2. `$CONFLUX_TPU_CACHE_DIR`,
-3. `~/.cache/conflux_tpu/xla` (created on demand).
+1. ``$JAX_COMPILATION_CACHE_DIR`` when it is set. JAX reads that variable
+   itself, so this module then sets no directory in code.
+2. Otherwise the fixed ``<repo>/.jax_cache`` (listed in .gitignore). The
+   path is part of the cache's key, so it never depends on the process,
+   the time or the user's home directory.
 
-Enabling is idempotent and *guarded*: on a backend/jax combination without
-persistent-cache support the call degrades to a no-op instead of raising —
-a missing cache only costs compile time, never correctness.
+A failure to configure the cache raises: a cache that silently stays
+off costs minutes per process and hides why.
 """
 
 from __future__ import annotations
 
 import os
 
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
 _ENABLED_AT: str | None = None
 
 
 def default_cache_dir() -> str:
-    """The resolved default cache directory (no filesystem side effects)."""
-    env = os.environ.get("CONFLUX_TPU_CACHE_DIR")
-    if env:
-        return env
-    return os.path.join(os.path.expanduser("~"), ".cache", "conflux_tpu",
-                        "xla")
+    """The directory :func:`enable_persistent_cache` would use (no
+    filesystem side effects)."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or REPO_CACHE_DIR
 
 
-def enable_persistent_cache(path: str | None = None, *,
-                            min_compile_secs: float = 10.0) -> str | None:
-    """Point jax's persistent compilation cache at a durable directory.
+def enable_persistent_cache(*, min_compile_secs: float = 10.0) -> str:
+    """Switch on jax's persistent compilation cache.
 
     `min_compile_secs` filters trivial programs out of the cache (the
     default 10 s keeps every at-scale factorization but skips the
     sub-second host utilities); the min-entry-size filter is zeroed so the
-    time threshold is the only admission rule — bench.py measured small
-    serialized executables for multi-minute compiles, and the byte filter
-    silently dropped them.
+    time threshold is the only admission rule — small serialized
+    executables of multi-minute compiles were otherwise dropped.
 
-    Returns the cache directory actually enabled, or None when the
-    environment does not support it. Safe to call many times (first call
-    wins; later calls with a different path are ignored rather than
-    re-pointing a live cache).
+    Returns the cache directory. Safe to call many times (first call
+    wins).
     """
     global _ENABLED_AT
     if _ENABLED_AT is not None:
         return _ENABLED_AT
-    cache = path or default_cache_dir()
-    try:
-        import jax
+    import jax
 
-        os.makedirs(cache, exist_ok=True)
+    cache = default_cache_dir()
+    os.makedirs(cache, exist_ok=True)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          min_compile_secs)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:
-        return None
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      min_compile_secs)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     _ENABLED_AT = cache
     return cache
 

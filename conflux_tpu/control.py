@@ -124,16 +124,15 @@ _SEED_KNOBS = ("max_batch_delay", "max_pending", "qos_contention")
 
 
 def operating_point_path() -> str:
-    """Where the operating-point rows live: beside the XLA cache dir
-    (`~/.cache/conflux_tpu/operating_point.json` by default), or
-    wherever `$CONFLUX_TPU_OPERATING_POINT` points (the test hook)."""
+    """Where the operating-point rows live: in the checkout's own cache
+    directory (`<repo>/.jax_cache/operating_point.json`), or wherever
+    `$CONFLUX_TPU_OPERATING_POINT` points (the test hook)."""
     p = os.environ.get("CONFLUX_TPU_OPERATING_POINT")
     if p:
         return p
     from conflux_tpu import cache
 
-    return os.path.join(os.path.dirname(cache.default_cache_dir()),
-                        "operating_point.json")
+    return os.path.join(cache.REPO_CACHE_DIR, "operating_point.json")
 
 
 def _validate_op_row(row) -> bool:

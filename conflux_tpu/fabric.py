@@ -872,6 +872,29 @@ def _raise_wire(reply: dict) -> None:
     raise RuntimeError(f"remote {et}: {em}")
 
 
+# libtpu belongs to one process at a time, and a worker whose environment
+# leaves JAX free to pick the TPU claims every chip the machine shows it.
+# So one front runs at most one such worker, and none while the front
+# holds the chip itself; more fail at start() instead of hanging the chip.
+_chip_lock = threading.Lock()
+_chip_workers: list = []  # guarded-by: _chip_lock
+
+
+def _may_claim_chip(env: dict) -> bool:
+    plats = env.get("JAX_PLATFORMS", "")
+    return not plats or "tpu" in plats.split(",")
+
+
+def _front_holds_tpu() -> bool:
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return False
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
 class ProcessHost(HostHandle):
     """An engine host in its own worker process.
 
@@ -934,13 +957,15 @@ class ProcessHost(HostHandle):
     def start(self) -> None:
         if self._conn is not None:
             return
+        env = dict(os.environ if self._env is None else self._env)
+        if _may_claim_chip(env):
+            self._claim_chip()
         os.makedirs(self.ckpt_dir, exist_ok=True)
         sock = os.path.join(self.ckpt_dir, "rpc.sock")
         if os.path.exists(sock):
             os.unlink(sock)
         key = secrets.token_bytes(16)
         self._listener = Listener(sock, family="AF_UNIX", authkey=key)
-        env = dict(os.environ if self._env is None else self._env)
         env["CONFLUX_FABRIC_KEY"] = key.hex()
         cmd = [sys.executable, "-m", "conflux_tpu.fabric", "--worker",
                "--host-id", self.host_id, "--connect", sock,
@@ -999,6 +1024,25 @@ class ProcessHost(HostHandle):
             target=self._recv_loop, daemon=True,
             name=f"fabric-recv-{self.host_id}")
         self._recv_thread.start()
+
+    def _claim_chip(self) -> None:
+        """Register this worker as the one that may reach the TPU, or
+        raise: a second one would find the chip taken."""
+        with _chip_lock:
+            _chip_workers[:] = [
+                h for h in _chip_workers
+                if h is not self and (h._proc is None
+                                      or h._proc.poll() is None)]
+            if _chip_workers or _front_holds_tpu():
+                holder = (_chip_workers[0].host_id if _chip_workers
+                          else "this front process")
+                raise RuntimeError(
+                    f"fabric worker {self.host_id} would claim the TPU, "
+                    f"which {holder} holds: one process per chip. Set "
+                    "JAX_PLATFORMS=cpu in the workers' env, or run one "
+                    "worker per machine with a front that has not "
+                    "touched the chip")
+            _chip_workers.append(self)
 
     def _wire_send(self, frame: dict) -> None:
         """Control-frame send for the wire pump — serialized with the

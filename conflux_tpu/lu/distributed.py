@@ -321,8 +321,8 @@ def _build(geom: LUGeometry, mesh_key, precision, backend: str,
                 # Pallas row-DMA alternative existed rounds 3-4 behind
                 # swap='dma' but was deleted unadopted per the
                 # pre-decided criterion (docs/ROUND3.md #3: hardware A/B
-                # or deletion — the chip never recovered to run it; see
-                # docs/ROUND4.md); git history has the kernel.
+                # or deletion — the chip never recovered to run it); git
+                # history has the kernel.
                 Aloc = Aloc.at[didx].set(disp_vals, mode="drop")
                 orig = jnp.where(
                     own_d, lax.dynamic_update_slice(orig, worig, (li,)), orig)
@@ -712,7 +712,7 @@ def lu_factor_distributed(shards, geom: LUGeometry, mesh,
     (An experimental `swap='dma'` Pallas row-DMA alternative to the XLA
     displacement scatter existed rounds 3-4; it was deleted unadopted
     per the pre-decided hardware-A/B-or-delete criterion when the chip
-    stayed unreachable — docs/ROUND4.md. Git history has the kernel and
+    stayed unreachable. Git history has the kernel and
     its staged probe protocol.)
     """
     from conflux_tpu.geometry import check_shards

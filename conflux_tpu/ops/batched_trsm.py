@@ -254,50 +254,45 @@ def blocked_trsm(T, b, *, lower: bool = True,
 
 
 # --------------------------------------------------------------------------- #
-# Pallas TPU kernel: block over batch x block step, VMEM accumulator
+# Pallas TPU kernel: one grid cell per batch element, VMEM accumulator
 # --------------------------------------------------------------------------- #
 #
-# Grid (B, nb), block-step dim innermost — TPU grids iterate sequentially
-# with the rightmost dimension fastest, so for each batch element the nb
-# block steps run in order against a persistent VMEM scratch holding the
-# running right-hand side (initialized from b at step 0, the
-# `_matmul_kernel` accumulator discipline). Per step the kernel brings in
-# one (np, bs) column panel of T and one (bs, bs) diagonal inverse,
-# produces one (bs, k) x block on the MXU, and downdates the
-# not-yet-solved rows of the VMEM accumulator with one panel GEMM —
-# masked arithmetically (f32 row-iota compare), never via i1 relayouts
-# (the Mosaic constraint `_lu_block_kernel` documents). Lane alignment:
-# production serve traffic pads RHS widths to power-of-two buckets
-# already; tiny k runs fine in interpret mode (the off-TPU correctness
-# path) and underfills lanes on real hardware — batch more RHS to fill.
+# Grid (B,): each cell brings in its whole (np, np) triangle, its (nb, bs,
+# bs) diagonal inverses and its (np, k) right-hand side, and runs the nb
+# block steps as a STATIC loop against a VMEM scratch holding the running
+# right-hand side (the `_matmul_kernel` accumulator discipline). Per step
+# one (bs, k) x block comes off the MXU and the not-yet-solved rows are
+# downdated with one (np, bs) panel GEMM — masked arithmetically (f32
+# row-iota compare), never via i1 relayouts (the Mosaic constraint
+# `_lu_block_kernel` documents). Whole-array blocks keep every block shape
+# legal for Mosaic (a (np, bs) column-panel block is not: bs=32 is neither
+# a lane multiple nor the array's width), and static panel slices need no
+# dynamic lane offsets. The triangle must fit VMEM: `pallas_blocked_trsm`
+# refuses larger ones on TPU.
+
+_MAX_TPU_N = 1024  # largest padded n whose described-v5e compile fits VMEM
 
 
 def _btrsm_kernel(t_ref, d_ref, b_ref, o_ref, acc_ref, *, nb: int,
                   bs: int, lower: bool):
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[:] = b_ref[0].astype(acc_ref.dtype)
-
-    # the block this step solves (index maps already brought in its
-    # panel/dinv and mapped the output window)
-    j = i if lower else nb - 1 - i
-    ri = acc_ref[pl.ds(j * bs, bs), :]
-    xi = jnp.dot(d_ref[0, 0].astype(acc_ref.dtype), ri,
-                 preferred_element_type=acc_ref.dtype)
-    o_ref[0] = xi.astype(o_ref.dtype)
-    # downdate rows not yet solved: below the block for a lower solve,
-    # above it for an upper one; the masked rows also null out the
-    # packed factor's other-triangle garbage in the full column panel
-    upd = jnp.dot(t_ref[0].astype(acc_ref.dtype), xi,
-                  preferred_element_type=acc_ref.dtype)
-    rows = lax.broadcasted_iota(jnp.int32, upd.shape, 0)
-    if lower:
-        maskf = (rows >= (j + 1) * bs).astype(acc_ref.dtype)
-    else:
-        maskf = (rows < j * bs).astype(acc_ref.dtype)
-    acc_ref[:] = acc_ref[:] - maskf * upd
+    acc_ref[:] = b_ref[0].astype(acc_ref.dtype)
+    rows = lax.broadcasted_iota(jnp.int32, acc_ref.shape, 0)
+    for j in (range(nb) if lower else range(nb - 1, -1, -1)):
+        ri = acc_ref[j * bs:(j + 1) * bs, :]
+        xi = jnp.dot(d_ref[0, j].astype(acc_ref.dtype), ri,
+                     preferred_element_type=acc_ref.dtype, precision=_HI)
+        o_ref[0, j * bs:(j + 1) * bs, :] = xi.astype(o_ref.dtype)
+        # downdate rows not yet solved: below the block for a lower
+        # solve, above it for an upper one; the masked rows also null
+        # out the packed factor's other-triangle garbage in the panel
+        upd = jnp.dot(t_ref[0, :, j * bs:(j + 1) * bs].astype(acc_ref.dtype),
+                      xi, preferred_element_type=acc_ref.dtype,
+                      precision=_HI)
+        if lower:
+            maskf = (rows >= (j + 1) * bs).astype(acc_ref.dtype)
+        else:
+            maskf = (rows < j * bs).astype(acc_ref.dtype)
+        acc_ref[:] = acc_ref[:] - maskf * upd
 
 
 @functools.partial(jax.jit,
@@ -308,21 +303,15 @@ def _pallas_btrsm(T, dinv, b, lower: bool, interpret: bool):
     k = b.shape[-1]
     acc_dtype = jnp.promote_types(T.dtype, jnp.float32)
     kern = functools.partial(_btrsm_kernel, nb=nb, bs=bs, lower=lower)
-    blk = (lambda bi, i: (bi, 0, i)) if lower \
-        else (lambda bi, i: (bi, 0, nb - 1 - i))
-    dblk = (lambda bi, i: (bi, i, 0, 0)) if lower \
-        else (lambda bi, i: (bi, nb - 1 - i, 0, 0))
-    oblk = (lambda bi, i: (bi, i, 0)) if lower \
-        else (lambda bi, i: (bi, nb - 1 - i, 0))
     return pl.pallas_call(
         kern,
-        grid=(B, nb),
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, np_, bs), blk),
-            pl.BlockSpec((1, 1, bs, bs), dblk),
-            pl.BlockSpec((1, np_, k), lambda bi, i: (bi, 0, 0)),
+            pl.BlockSpec((1, np_, np_), lambda bi: (bi, 0, 0)),
+            pl.BlockSpec((1, nb, bs, bs), lambda bi: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, np_, k), lambda bi: (bi, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bs, k), oblk),
+        out_specs=pl.BlockSpec((1, np_, k), lambda bi: (bi, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, np_, k), b.dtype),
         scratch_shapes=[pltpu.VMEM((np_, k), acc_dtype)],
         cost_estimate=pl.CostEstimate(
@@ -352,5 +341,9 @@ def pallas_blocked_trsm(T, dinv, b, *, lower: bool = True):
         T = _pad_identity(T, np_)
         b = jnp.pad(b, ((0, 0), (0, np_ - n), (0, 0)))
     interpret = jax.default_backend() != "tpu"
+    if not interpret and np_ > _MAX_TPU_N:
+        raise ValueError(
+            f"pallas blocked trsm: a padded n={np_} triangle does not fit "
+            f"VMEM on TPU (at most {_MAX_TPU_N}); use backend='xla'")
     x = _pallas_btrsm(T, dinv, b, lower, interpret)
     return x[:, :n]

@@ -75,7 +75,7 @@ def gemm(a: jax.Array, b: jax.Array, c: jax.Array | None = None,
     if backend == "pallas":
         from conflux_tpu.ops import pallas_kernels
 
-        out = pallas_kernels.gemm(a, b)
+        out = pallas_kernels.gemm(a, b, precision=precision)
     else:
         out = jnp.matmul(
             a, b,
@@ -256,13 +256,15 @@ def trsm_left_lower_t(L: jax.Array, B: jax.Array) -> jax.Array:
 # explicit override. Overridable because no public API queries scoped VMEM.
 _SCOPED_VMEM_BYTES = None  # explicit override (set_scoped_vmem_bytes)
 
-# budget per device kind, bytes. Only v5e is measured; other rows inherit
-# the conservative v5e figure until measured on hardware.
+# budget per TPU device kind, bytes. Only v5e is measured; a TPU kind
+# missing here is an error (scoped_vmem_bytes), never a silent guess.
 _SCOPED_VMEM_TABLE = {
     "v5 lite": 32 << 20,
     "v5e": 32 << 20,
 }
-_SCOPED_VMEM_DEFAULT = 32 << 20
+# the budget of the MODEL off-TPU (CPU tests, the NumPy spec): the v5e
+# figure, so CPU runs size chunks exactly as the measured chip does
+_MODEL_VMEM_BYTES = 32 << 20
 
 
 def set_scoped_vmem_bytes(n: int | None) -> None:
@@ -277,9 +279,9 @@ def set_scoped_vmem_bytes(n: int | None) -> None:
 
 def scoped_vmem_bytes() -> int:
     """The scoped-VMEM budget bounding a single LU/QR custom call's
-    operand: override > $CONFLUX_TPU_SCOPED_VMEM_BYTES > device-kind
-    table > conservative default. Device detection may initialize a
-    backend; pure-host callers (e.g. the NumPy spec) pass an explicit
+    operand: override > $CONFLUX_TPU_SCOPED_VMEM_BYTES > TPU device-kind
+    table (an unknown TPU kind raises) > the model budget off-TPU.
+    Device detection may initialize a backend; pure-host callers (e.g. the NumPy spec) pass an explicit
     `budget` to the ceiling helpers instead of reaching this."""
     if _SCOPED_VMEM_BYTES is not None:
         return _SCOPED_VMEM_BYTES
@@ -301,14 +303,17 @@ def scoped_vmem_bytes() -> int:
                 f"CONFLUX_TPU_SCOPED_VMEM_BYTES={env}: implausible "
                 "scoped VMEM budget (< 1 MiB)")
         return n
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-        for key, budget in _SCOPED_VMEM_TABLE.items():
-            if key in kind:
-                return budget
-    except Exception:
-        pass
-    return _SCOPED_VMEM_DEFAULT
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return _MODEL_VMEM_BYTES
+    kind = dev.device_kind.lower()
+    for key, budget in _SCOPED_VMEM_TABLE.items():
+        if key in kind:
+            return budget
+    raise ValueError(
+        f"no scoped-VMEM budget for TPU device kind {dev.device_kind!r}: "
+        "measure it and add it to _SCOPED_VMEM_TABLE, or pass one through "
+        "set_scoped_vmem_bytes / $CONFLUX_TPU_SCOPED_VMEM_BYTES")
 
 
 def single_call_rows(v: int, dtype=jnp.float32, budget: int | None = None
@@ -355,12 +360,11 @@ def set_panel_algo(name: str) -> None:
 
 
 # VMEM ceiling of the Pallas elimination kernel: the (m, 128) block, the
-# lane-padded (m, 1) masks/temporaries and the double-buffered outputs must
-# stay under the 16 MiB scoped VMEM (m=8192 measured 3.8 MB over). This is
-# a property of the KERNEL's scratch layout (128 lanes x 4 B x ~8 buffers
-# -> 16 MiB / 4 KiB = 4096 rows), not of the LU custom call's budget —
-# a module var (not derived per-call) so tests can shrink the ceiling.
-_PALLAS_MAX_ROWS = 4096
+# int8 mask, the (m, 128) temporaries and the outputs must stay under the
+# 16 MiB scoped VMEM. A described-v5e compile fits m=2048 and refuses
+# m=4096 (16.88 MiB). A module var (not derived per-call) so tests can
+# shrink the ceiling.
+_PALLAS_MAX_ROWS = 2048
 
 
 def _pallas_panel_ok(dtype, m: int, v: int) -> bool:
@@ -577,7 +581,7 @@ def panel_lu_pallas(panel: jax.Array):
     rows keep their positions, an `alive` mask shrinks, and the inter-block
     update is a row-gathered TRSM plus one masked MXU GEMM. Rows are gathered
     into LAPACK order exactly once at the end. This sidesteps the serial
-    row-swapping LU custom call entirely (VMEM bound: m <= 4096, see
+    row-swapping LU custom call entirely (VMEM bound: m <= 2048, see
     `pallas_kernels.lu_block`; taller panels go through
     `panel_lu(algo='pallas')`, which routes them to the tournament with
     pallas chunks).

@@ -51,8 +51,8 @@ contract that gives bitwise parity between ``plan.factor`` (bucket 1)
 and the coalesced factor lane. Off-TPU the kernels run in interpret
 mode (the correctness-test path, like `pallas_blocked_trsm`); f64 is
 interpret-only (Mosaic has no f64), and the VMEM working set bounds
-the padded size at roughly Np <= 1024 on hardware (a handful of
-(Np, Np) f32 arrays against the ~16 MB scoped VMEM).
+the padded size on hardware: a described-v5e compile fits Np=512 in the
+16 MiB scoped VMEM and refuses Np=1024, so larger sizes raise on TPU.
 """
 
 from __future__ import annotations
@@ -68,6 +68,7 @@ from jax.experimental.pallas import tpu as pltpu
 from conflux_tpu.ops.batched_trsm import _pad_identity
 
 _PANEL_W = 128  # elimination-step chunk == one lane tile (grid dim 1)
+_MAX_TPU_N = 512  # largest padded N whose described-v5e compile fits VMEM
 
 
 def _pow2(n: int) -> int:
@@ -81,6 +82,17 @@ def _check_batched_square(A) -> None:
     if A.ndim != 3 or A.shape[-1] != A.shape[-2]:
         raise ValueError(
             f"batched factor kernels take (B, N, N), got {A.shape}")
+
+
+def _interpret(m: int) -> bool:
+    """Interpret mode off-TPU only; on TPU a width the kernel's VMEM
+    working set cannot hold is refused, never run some other way."""
+    interpret = jax.default_backend() != "tpu"
+    if not interpret and m > _MAX_TPU_N:
+        raise ValueError(
+            f"pallas factor kernels: padded N={m} does not fit VMEM on TPU "
+            f"(at most {_MAX_TPU_N}); use backend='xla'")
+    return interpret
 
 
 # --------------------------------------------------------------------------- #
@@ -104,13 +116,14 @@ def _blu_kernel(a_ref, w_ref, o_ref, piv_ref, wa_ref, acc_ref, alive_ref,
         alive_ref[:] = jnp.ones_like(alive_ref)
         # fused probe row: wA = w^T A off the pristine VMEM-resident
         # input block — no second factor-time pass re-reading A
-        wa_ref[:] = jnp.dot(
+        wa_ref[0] = jnp.dot(
             w_ref[:].astype(acc_ref.dtype), a_ref[0].astype(acc_ref.dtype),
-            preferred_element_type=acc_ref.dtype).astype(wa_ref.dtype)
+            preferred_element_type=acc_ref.dtype,
+            precision=lax.Precision.HIGHEST).astype(wa_ref.dtype)
 
     rows = lax.broadcasted_iota(jnp.int32, (m, m), 0)
     cols = lax.broadcasted_iota(jnp.int32, (m, m), 1)
-    colsb = lax.broadcasted_iota(jnp.int32, (1, 1, bw), 2)
+    colsp = lax.broadcasted_iota(jnp.int32, (1, 1, m), 2)
     base = i * bw
 
     def body(jj, carry):
@@ -143,7 +156,7 @@ def _blu_kernel(a_ref, w_ref, o_ref, piv_ref, wa_ref, acc_ref, alive_ref,
         A = A * (1.0 - maskf) + lmul * maskf
         acc_ref[:] = A
         alive_ref[:] = live_f
-        piv_ref[:] = jnp.where(colsb == jj, p, piv_ref[:])
+        piv_ref[:] = jnp.where(colsp == j, p, piv_ref[:])
         return carry
 
     jax.lax.fori_loop(0, bw, body, 0)
@@ -165,9 +178,10 @@ def _bchol_kernel(a_ref, w_ref, o_ref, wa_ref, acc_ref, *, bw: int):
     @pl.when(i == 0)
     def _init():
         acc_ref[:] = a_ref[0].astype(acc_ref.dtype)
-        wa_ref[:] = jnp.dot(
+        wa_ref[0] = jnp.dot(
             w_ref[:].astype(acc_ref.dtype), a_ref[0].astype(acc_ref.dtype),
-            preferred_element_type=acc_ref.dtype).astype(wa_ref.dtype)
+            preferred_element_type=acc_ref.dtype,
+            precision=lax.Precision.HIGHEST).astype(wa_ref.dtype)
 
     rows = lax.broadcasted_iota(jnp.int32, (m, m), 0)
     cols = lax.broadcasted_iota(jnp.int32, (m, m), 1)
@@ -226,13 +240,13 @@ def _pallas_blu(A, w, interpret: bool):
         ],
         out_specs=(
             pl.BlockSpec((1, m, m), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, 1, bw), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, m), lambda b, i: (b, 0)),
+            pl.BlockSpec((1, 1, m), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, 1, m), lambda b, i: (b, 0, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((B, m, m), A.dtype),
-            jax.ShapeDtypeStruct((B, nsteps, bw), jnp.int32),
-            jax.ShapeDtypeStruct((B, m), acc_dt),
+            jax.ShapeDtypeStruct((B, 1, m), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, m), acc_dt),
         ),
         scratch_shapes=[
             pltpu.VMEM((m, m), acc_dt),
@@ -247,9 +261,9 @@ def _pallas_blu(A, w, interpret: bool):
     )(A, w)
     # rows into LAPACK order: position k's row is the step-k pivot
     # winner (square elimination freezes every row exactly once)
-    gpiv = piv.reshape(B, m)
+    gpiv = piv[:, 0]
     LU = jnp.take_along_axis(out, gpiv[..., None], axis=1)
-    return LU, gpiv, wa
+    return LU, gpiv, wa[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -259,7 +273,7 @@ def _pallas_bchol(A, w, interpret: bool):
     nsteps = m // bw
     acc_dt = jnp.promote_types(A.dtype, jnp.float32)
     kern = functools.partial(_bchol_kernel, bw=bw)
-    return pl.pallas_call(
+    L, wa = pl.pallas_call(
         kern,
         grid=(B, nsteps),
         in_specs=[
@@ -268,11 +282,11 @@ def _pallas_bchol(A, w, interpret: bool):
         ],
         out_specs=(
             pl.BlockSpec((1, m, m), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, m), lambda b, i: (b, 0)),
+            pl.BlockSpec((1, 1, m), lambda b, i: (b, 0, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((B, m, m), A.dtype),
-            jax.ShapeDtypeStruct((B, m), acc_dt),
+            jax.ShapeDtypeStruct((B, 1, m), acc_dt),
         ),
         scratch_shapes=[pltpu.VMEM((m, m), acc_dt)],
         cost_estimate=pl.CostEstimate(
@@ -282,6 +296,7 @@ def _pallas_bchol(A, w, interpret: bool):
         ),
         interpret=interpret,
     )(A, w)
+    return L, wa[:, 0]
 
 
 def _pad_batch_floor(Ap):
@@ -326,7 +341,7 @@ def pallas_lu_factor_batched(A, *, probe_w=None):
     acc_dt = jnp.promote_types(A.dtype, jnp.float32)
     Ap = _pad_batch_floor(_pad_identity(A, m))
     w = _probe_input(probe_w, n, m, acc_dt)
-    interpret = jax.default_backend() != "tpu"
+    interpret = _interpret(m)
     LU, perm, wa = _pallas_blu(Ap, w, interpret)
     LU, perm = LU[:B, :n, :n], perm[:B, :n]
     if probe_w is None:
@@ -348,7 +363,7 @@ def pallas_cholesky_factor_batched(A, *, probe_w=None):
     acc_dt = jnp.promote_types(A.dtype, jnp.float32)
     Ap = _pad_batch_floor(_pad_identity(A, m))
     w = _probe_input(probe_w, n, m, acc_dt)
-    interpret = jax.default_backend() != "tpu"
+    interpret = _interpret(m)
     L, wa = _pallas_bchol(Ap, w, interpret)
     L = L[:B, :n, :n]
     if probe_w is None:

@@ -29,39 +29,17 @@ AXIS_X = "x"  # row dimension of the tile grid (Px)
 AXIS_Y = "y"  # column dimension of the tile grid (Py)
 AXIS_Z = "z"  # 2.5D replication depth (Pz)
 
-# jax-version shim: `jax.shard_map` graduated from
-# `jax.experimental.shard_map.shard_map` only in newer jax releases; this
-# environment ships 0.4.37 where only the experimental spelling exists.
-# Every shard_map program in the package routes through this name.
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-        # check_rep=False: the legacy replication tracker cannot follow
-        # fori_loop carries that start replicated and turn varying inside
-        # the body (jax's own error message recommends exactly this
-        # workaround); the algorithms re-establish replication explicitly
-        # via `replicate` before any out_spec that claims it, so the
-        # check adds nothing here.
-        kwargs.setdefault("check_rep", False)
-        return _shard_map_exp(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kwargs)
+shard_map = jax.shard_map  # every shard_map program routes through here
 
 
 def pvary(val, axes):
     """Mark a literal as varying over mesh `axes` — `lax.pcast`'s
     "varying manual axes" vocabulary, needed so `lax.cond` branch output
-    types match mask-dependent compute branches on new jax. Old jax
-    (<= 0.4.x, the experimental shard_map) has no pcast: its check_rep
-    machinery inserts the equivalent rewrites itself, so this is an
-    identity there."""
+    types match mask-dependent compute branches."""
     from jax import lax
 
-    if hasattr(lax, "pcast"):
-        for ax in axes:
-            val = lax.pcast(val, ax, to="varying")
+    for ax in axes:
+        val = lax.pcast(val, ax, to="varying")
     return val
 
 comm = {

@@ -66,18 +66,12 @@ def _count_compile(event, duration, **kwargs) -> None:
             _compiles += 1
 
 
-try:  # private jax surface: degrade to a frozen counter if it moves
-    from jax._src import monitoring as _jax_monitoring
-
-    _jax_monitoring.register_event_duration_secs_listener(_count_compile)
-except Exception:  # noqa: BLE001 — the counter is observability only
-    _jax_monitoring = None
+jax.monitoring.register_event_duration_secs_listener(_count_compile)
 
 
 def compile_count() -> int:
     """Total XLA backend compiles this process has paid (all devices,
-    all programs — monotone; window it by differencing). 0 forever when
-    the jax monitoring hook is unavailable."""
+    all programs — monotone; window it by differencing)."""
     with _PROF_LOCK:
         return _compiles
 

@@ -275,7 +275,6 @@ def _build_full(geom, mesh_key, precision, backend: str, chunk: int,
     def _vary(val):
         # mark a literal as varying over every mesh axis so lax.cond
         # branch output types match the mask-dependent compute branches
-        # (identity on old jax, where check_rep handles this — see pvary)
         return pvary(val, (AXIS_X, AXIS_Y, AXIS_Z))
 
     def device_fn(blk, rblk=None, k0=0, k_end=n_steps):

@@ -79,6 +79,37 @@ from conflux_tpu.update import (
     woodbury_apply,
 )
 
+# Every solve program computes its right-hand sides in column tiles of this
+# fixed width. XLA picks its dot kernel by the RHS width (under jax 0.9.0
+# the CPU backend has separate kernels at width 1, 2-16, 32 and 64, with
+# different accumulation orders), so without tiles one column's bits would
+# depend on how many requests the engine coalesced with it — and coalesced
+# answers must be bitwise `SolveSession.solve`'s (DESIGN §19).
+RHS_TILE = 8
+
+
+def _rhs_tiles(fn, *cols):
+    """fn over RHS_TILE-wide column tiles of `cols` (each (..., n_i, nb),
+    nb a power of two; narrower buckets zero-pad to one tile). Returns
+    fn's outputs with a leading tile axis. A single tile calls fn
+    directly; more ride one `lax.map`, which compiles the same tile body."""
+    nb = cols[0].shape[-1]
+    if nb <= RHS_TILE:
+        pad = RHS_TILE - nb
+        cols = [jnp.pad(c, [(0, 0)] * (c.ndim - 1) + [(0, pad)])
+                for c in cols]
+        return jax.tree.map(lambda o: o[None], fn(*cols))
+    t = nb // RHS_TILE
+    split = [jnp.moveaxis(c.reshape(c.shape[:-1] + (t, RHS_TILE)), -2, 0)
+             for c in cols]
+    return lax.map(lambda cs: fn(*cs), split)
+
+
+def _untile(x, nb: int):
+    """(t, ..., n, RHS_TILE) column tiles back to (..., n, nb)."""
+    x = jnp.moveaxis(x, 0, -2)
+    return x.reshape(x.shape[:-2] + (-1,))[..., :nb]
+
 
 @dataclasses.dataclass(frozen=True)
 class PlanKey:
@@ -858,13 +889,18 @@ class FactorPlan:
         k = self.key
         corr = self._base_corr(factors)
         cdtype = blas.compute_dtype(jnp.dtype(k.dtype))
-        x = corr(b2).astype(cdtype)
-        for _ in range(k.refine if sweeps is None else sweeps):
-            r = (b2.astype(cdtype)
-                 - jnp.matmul(A.astype(cdtype), x,
-                              precision=lax.Precision.HIGHEST))
-            x = x + corr(r).astype(cdtype)
-        return x
+        sweeps = k.refine if sweeps is None else sweeps
+
+        def tile(b):
+            x = corr(b).astype(cdtype)
+            for _ in range(sweeps):
+                r = (b.astype(cdtype)
+                     - jnp.matmul(A.astype(cdtype), x,
+                                  precision=lax.Precision.HIGHEST))
+                x = x + corr(r).astype(cdtype)
+            return x
+
+        return _untile(_rhs_tiles(tile, b2), b2.shape[-1])
 
     def _build_factor(self):
         fn = self._one_factor
@@ -1541,21 +1577,25 @@ class FactorPlan:
 
         k = self.key
         cdtype = blas.compute_dtype(jnp.dtype(k.dtype))
-        if k.spd:
-            L, Dl = factors
-            Lc = L.astype(Dl.dtype)
-            y = blocked_solve(Lc, Dl, b2.astype(Dl.dtype), lower=True)
-            Du = jnp.swapaxes(Dl.conj(), -1, -2)
-            x, xsum, wAx = blocked_solve_probe(
-                Lc.conj().T, Du, y, wA, lower=False, stats_dtype=cdtype)
-        else:
+
+        def tile(b):
+            if k.spd:
+                L, Dl = factors
+                Lc = L.astype(Dl.dtype)
+                y = blocked_solve(Lc, Dl, b.astype(Dl.dtype), lower=True)
+                Du = jnp.swapaxes(Dl.conj(), -1, -2)
+                return blocked_solve_probe(
+                    Lc.conj().T, Du, y, wA, lower=False, stats_dtype=cdtype)
             LU, Dl, Du, perm = factors
             LUc = LU.astype(Dl.dtype)
-            y = blocked_solve(LUc, Dl, b2.astype(Dl.dtype)[perm],
-                              lower=True)
-            x, xsum, wAx = blocked_solve_probe(
+            y = blocked_solve(LUc, Dl, b.astype(Dl.dtype)[perm], lower=True)
+            return blocked_solve_probe(
                 LUc, Du, y, wA, lower=False, stats_dtype=cdtype)
-        return x.astype(cdtype), xsum, wAx
+
+        x, xsum, wAx = _rhs_tiles(tile, b2)
+        # column 0, the probe's column, lives in tile 0
+        return (_untile(x, b2.shape[-1]).astype(cdtype), jnp.sum(xsum),
+                wAx[0])
 
     def _solve_health_fn(self, nrhs: int):
         """The checked substitution program per RHS bucket — what
@@ -1618,11 +1658,15 @@ class FactorPlan:
         self._bump("refine")
         corr = self._base_corr(factors)
         cdtype = blas.compute_dtype(jnp.dtype(self.key.dtype))
-        xc = x.astype(cdtype)
-        r = (b2.astype(cdtype)
-             - jnp.matmul(A0.astype(cdtype), xc,
-                          precision=lax.Precision.HIGHEST))
-        return xc + corr(r).astype(cdtype)
+
+        def tile(x, b):
+            xc = x.astype(cdtype)
+            r = (b.astype(cdtype)
+                 - jnp.matmul(A0.astype(cdtype), xc,
+                              precision=lax.Precision.HIGHEST))
+            return xc + corr(r).astype(cdtype)
+
+        return _untile(_rhs_tiles(tile, x, b2), b2.shape[-1])
 
     def _refine_fn(self, nrhs: int):
         def build():
@@ -1777,12 +1821,16 @@ class FactorPlan:
         self._bump("update_solve")
         corr = self._base_corr(factors)
         cdtype = blas.compute_dtype(jnp.dtype(self.key.dtype))
-        x = woodbury_apply(corr, Y, Cinv, Vp, b2).astype(cdtype)
-        bc = b2.astype(cdtype)
-        for _ in range(sweeps):
-            r = bc - updated_matvec(A0, Up, Vp, x)
-            x = x + woodbury_apply(corr, Y, Cinv, Vp, r).astype(cdtype)
-        return x
+
+        def tile(b):
+            x = woodbury_apply(corr, Y, Cinv, Vp, b).astype(cdtype)
+            bc = b.astype(cdtype)
+            for _ in range(sweeps):
+                r = bc - updated_matvec(A0, Up, Vp, x)
+                x = x + woodbury_apply(corr, Y, Cinv, Vp, r).astype(cdtype)
+            return x
+
+        return _untile(_rhs_tiles(tile, b2), b2.shape[-1])
 
     def _update_fn(self, kb: int):
         """Jitted capacitance-assembly program per rank bucket kb:

@@ -132,7 +132,7 @@ def _select_tournament(cand: np.ndarray, gri_m: np.ndarray, Px: int, v: int,
     # dtype is a property of the INPUT (mirrors the impl's compute-dtype
     # resolution), so the spec stays host-independent AND synchronized.
     cap = blas.batched_call_rows(v, _np_compute_dtype(stack.dtype),
-                                 budget=blas._SCOPED_VMEM_DEFAULT)
+                                 budget=blas._MODEL_VMEM_BYTES)
     lu00, wid = _tournament_winners_np(stack, v, min(chunk, cap))
     gpiv = _take_fill(sids, wid, _ID_SENTINEL)
     return gpiv, lu00
@@ -202,13 +202,13 @@ def simulate_lu(A: np.ndarray, grid: Grid3, v: int, pivoting: str = "tournament"
     for buffer-exact cross-validation in the chunked regime.
 
     Divergence caveat: the spec pins its chunk ceilings to the 32 MiB
-    `blas._SCOPED_VMEM_DEFAULT` so the simulation is host-independent,
+    `blas._MODEL_VMEM_BYTES` so the simulation is host-independent,
     while the implementation honors CONFLUX_TPU_SCOPED_VMEM_BYTES /
     `set_scoped_vmem_bytes` / the device-kind table. When such an
     override is active, default-chunk runs of the two can elect
     different pivots (different nomination brackets). For buffer-exact
     cross-validation either pass an explicit `panel_chunk` to BOTH, or
-    assert `blas.scoped_vmem_bytes() == blas._SCOPED_VMEM_DEFAULT`
+    assert `blas.scoped_vmem_bytes() == blas._MODEL_VMEM_BYTES`
     first (the spec-vs-impl tests do).
     """
     if panel_chunk is None:
@@ -218,7 +218,7 @@ def simulate_lu(A: np.ndarray, grid: Grid3, v: int, pivoting: str = "tournament"
         # dtype from the input, mirroring lu_factor_distributed
         panel_chunk = blas.single_call_rows(
             v, _np_compute_dtype(np.asarray(A).dtype),
-            budget=blas._SCOPED_VMEM_DEFAULT)
+            budget=blas._MODEL_VMEM_BYTES)
     select = PIVOTING_STRATEGIES[pivoting]
     geom = LUGeometry.create(A.shape[0], A.shape[1], v, grid)
     Px, Py, Pz = grid.Px, grid.Py, grid.Pz

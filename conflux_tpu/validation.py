@@ -10,6 +10,7 @@ result of a distributed run.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -378,5 +379,238 @@ def _build_qr_residual(geom, mesh_key):
                   P(AXIS_X, AXIS_Y, None, None),
                   P(AXIS_X, AXIS_Y, None, None)),
         out_specs=(P(), P(), P()),
+    )
+    return jax.jit(fn)
+
+
+# --------------------------------------------------------------------------- #
+# Test matrices generated where they live, and oracles that regenerate them
+# --------------------------------------------------------------------------- #
+#
+# At chip scale the matrix cannot come from the host (a 4 GB transfer per
+# run) and cannot be kept next to its factors (A + LU + a product exhausts
+# a 16 GB chip at N=32768). So entry (i, j) is a counter-based hash of
+# (seed, i, j): any block is generated on the device that holds it, with no
+# communication, and an oracle regenerates exactly the rows it needs. The
+# family is `make_test_matrix`'s — uniform[-1, 1) entries plus 2 on the
+# diagonal — and, with `spd=True`, `make_spd_matrix`'s role: symmetric
+# uniform entries scaled by 1/sqrt(n) plus 2I (eigenvalues in ~[0.85, 3.2]).
+
+
+def _fmix32(h):
+    h = h ^ (h >> 16)
+    h = h * jnp.uint32(0x85EBCA6B)
+    h = h ^ (h >> 13)
+    h = h * jnp.uint32(0xC2B2AE35)
+    return h ^ (h >> 16)
+
+
+def seeded_entries(rows, cols, n: int, seed: int, spd: bool = False):
+    """Entries A[rows, cols] of the seeded n x n test matrix, float32.
+    `rows`/`cols` are broadcastable int32 arrays of global indices.
+    Traceable; the same (seed, i, j) always gives the same bits."""
+    r = jnp.asarray(rows).astype(jnp.uint32)
+    c = jnp.asarray(cols).astype(jnp.uint32)
+    if spd:
+        r, c = jnp.minimum(r, c), jnp.maximum(r, c)
+    h = _fmix32(r * jnp.uint32(n) + c
+                ^ _fmix32(jnp.uint32(seed & 0xFFFFFFFF)))
+    u = (h >> 8).astype(jnp.float32) * np.float32(2.0 ** -23) - 1.0
+    if spd:
+        u = u / np.float32(np.sqrt(n))
+    eye = (jnp.asarray(rows) == jnp.asarray(cols)).astype(jnp.float32)
+    return u + 2.0 * eye
+
+
+@functools.lru_cache(maxsize=8)
+def _build_seeded_shards(geom, mesh_key, seed: int, spd: bool):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from conflux_tpu.parallel.mesh import AXIS_X, AXIS_Y, lookup_mesh
+
+    mesh = lookup_mesh(mesh_key)
+    v, Px, Py = geom.v, geom.grid.Px, geom.grid.Py
+    n = geom.N
+
+    def make():
+        lr = jnp.arange(geom.Ml, dtype=jnp.int32)
+        lc = jnp.arange(geom.Nl, dtype=jnp.int32)
+        px = jnp.arange(Px, dtype=jnp.int32)[:, None]
+        py = jnp.arange(Py, dtype=jnp.int32)[:, None]
+        grow = ((lr // v) * Px + px) * v + lr % v  # (Px, Ml)
+        gcol = ((lc // v) * Py + py) * v + lc % v  # (Py, Nl)
+        return seeded_entries(grow[:, None, :, None], gcol[None, :, None, :],
+                            n, seed, spd)
+
+    return jax.jit(make, out_shardings=NamedSharding(
+        mesh, P(AXIS_X, AXIS_Y, None, None)))
+
+
+def seeded_shards(geom, mesh, seed: int, spd: bool = False):
+    """Block-cyclic shards (Px, Py, Ml, Nl) of the seeded test matrix,
+    each generated on the device that holds it. `geom` is an LUGeometry
+    or CholeskyGeometry of a square problem."""
+    from conflux_tpu.parallel.mesh import mesh_cache_key
+
+    return _build_seeded_shards(geom, mesh_cache_key(mesh), int(seed),
+                              bool(spd))()
+
+
+@functools.lru_cache(maxsize=8)
+def _build_strips(n: int, blk: int, seed: int, spd: bool):
+    @jax.jit
+    def ssq(F, perm):
+        rows = jnp.arange(n, dtype=jnp.int32)
+        F = F.astype(jnp.float32)
+        rss = jnp.zeros((), jnp.float32)
+        ass = jnp.zeros((), jnp.float32)
+        for i in range(0, n, blk):
+            ri = rows[i:i + blk, None]
+            # A[perm] strip regenerated: A itself never exists here
+            Ap_i = seeded_entries(ri if spd else perm[i:i + blk, None],
+                                  rows[None, :], n, seed, spd)
+            if spd:
+                Li = jnp.where(ri >= rows[None, :], F[i:i + blk], 0.0)
+            else:
+                Li = jnp.where(ri > rows[None, :], F[i:i + blk], 0.0) \
+                    + jnp.eye(blk, n, i, dtype=F.dtype)
+            acc = jnp.zeros((blk, n), jnp.float32)
+            for j in range(0, n, blk):
+                if spd:  # (L^T)[:, j-strip] = (lower part of rows j..)^T
+                    Uj = jnp.where(rows[j:j + blk, None] >= rows[None, :],
+                                   F[j:j + blk], 0.0).T
+                else:
+                    Uj = jnp.where(rows[:, None] <= rows[None, j:j + blk],
+                                   F[:, j:j + blk], 0.0)
+                acc = lax.dynamic_update_slice(
+                    acc, jnp.matmul(Li, Uj, precision=lax.Precision.HIGHEST),
+                    (0, j))
+            R = Ap_i - acc
+            rss = rss + jnp.sum(R * R)
+            ass = ass + jnp.sum(Ap_i * Ap_i)
+        return rss, ass
+
+    return ssq
+
+
+def _strips_residual(F, perm, seed: int, spd: bool, block: int) -> float:
+    n = F.shape[0]
+    blk = math.gcd(n, block)
+    if n // blk > 64:
+        raise ValueError(
+            f"residual strips need a height dividing n={n} and {block}; "
+            f"gcd {blk} would unroll {n // blk} strips")
+    if perm is None:
+        perm = jnp.arange(n, dtype=jnp.int32)
+    rss, ass = _build_strips(n, blk, int(seed), spd)(F, perm)
+    return _norm_ratio(rss, ass)
+
+
+def lu_residual_strips(LU, perm, seed: int, block: int = 4096) -> float:
+    """||A[perm] - L U||_F / ||A||_F on ONE device for the seeded test
+    matrix A (`seeded_entries`), exact. (blk, n) strips of L and (n, blk)
+    strips of U, with A[perm]'s strip regenerated in place, keep peak
+    memory at LU + O(strip): 2 n^3 flops, ~3 s at n=32768 on a v5e. The
+    strip height is gcd(n, block); sizes whose gcd would unroll into
+    more than 64 strips are rejected."""
+    return _strips_residual(LU, perm, seed, False, block)
+
+
+def cholesky_residual_strips(L, seed: int, block: int = 4096) -> float:
+    """||S - L L^T||_F / ||S||_F on ONE device for the seeded SPD test
+    matrix S (`seeded_entries(..., spd=True)`), exact, in the strips of
+    :func:`lu_residual_strips` (only L's lower triangle is read)."""
+    return _strips_residual(L, None, seed, True, block)
+
+
+def lu_residual_projected(LU_shards, perm, geom, mesh, seed: int,
+                          k: int = 16) -> float:
+    """Projected ||A[perm] - L U||_F / ||A||_F of distributed LU factors
+    of the seeded test matrix, on the mesh in O(n^2 k) work: with X an
+    (n, k) standard normal block, E ||R X||_F^2 = k ||R||_F^2, so
+    ||R X||_F / sqrt(k) estimates ||R||_F (relative spread ~sqrt(2/k)).
+    U X, then L (U X), then A[perm] X with A regenerated tile by tile —
+    nothing but the factors is resident, which is what lets the check run
+    next to N=65536 factors on 16 GB chips. ||A||_F is exact."""
+    from conflux_tpu.parallel.mesh import mesh_cache_key
+
+    fn = _build_lu_projected(geom, mesh_cache_key(mesh), int(seed), int(k))
+    X = jax.random.normal(jax.random.PRNGKey(seed), (geom.N, k),
+                          jnp.float32)
+    rss, ass = fn(LU_shards, jnp.asarray(perm, jnp.int32), X)
+    return float(np.sqrt(float(rss) / k) / max(np.sqrt(float(ass)), 1e-30))
+
+
+@functools.lru_cache(maxsize=8)
+def _build_lu_projected(geom, mesh_key, seed: int, k: int):
+    from jax.sharding import PartitionSpec as P
+
+    from conflux_tpu.parallel.mesh import (
+        AXIS_X, AXIS_Y, AXIS_Z, lookup_mesh, pvary, shard_map,
+    )
+
+    mesh = lookup_mesh(mesh_key)
+    v, Px, Py = geom.v, geom.grid.Px, geom.grid.Py
+    Ml, Nl, n = geom.Ml, geom.Nl, geom.N
+    Ntl = Nl // v
+
+    def device_fn(LUblk, perm, X):
+        x = lax.axis_index(AXIS_X)
+        y = lax.axis_index(AXIS_Y)
+        LUloc = LUblk[0, 0].astype(jnp.float32)
+        lr = jnp.arange(Ml, dtype=jnp.int32)
+        gp = ((lr // v) * Px + x) * v + lr % v  # my factor rows' positions
+        lc = jnp.arange(Nl, dtype=jnp.int32)
+        gcol = ((lc // v) * Py + y) * v + lc % v
+        orig = jnp.take(perm, gp)  # original rows behind those positions
+        i0 = jnp.zeros((), jnp.int32)
+        axes = (AXIS_X, AXIS_Y, AXIS_Z)
+
+        def tile(t):
+            c0 = (t * v).astype(jnp.int32)
+            return (lax.dynamic_slice(LUloc, (i0, c0), (Ml, v)),
+                    lax.dynamic_slice(gcol, (c0,), (v,)))
+
+        def ux_step(t, acc):
+            blk, gct = tile(t)
+            Ut = jnp.where(gp[:, None] <= gct[None, :], blk, 0.0)
+            return acc + jnp.matmul(Ut, jnp.take(X, gct, axis=0),
+                                    precision=lax.Precision.HIGHEST)
+
+        UXp = lax.psum(lax.fori_loop(
+            0, Ntl, ux_step, pvary(jnp.zeros((Ml, k), jnp.float32), axes)),
+            AXIS_Y)
+        # every device needs U X at its own columns: assemble it whole
+        UX = lax.psum(jnp.zeros((n, k), jnp.float32).at[gp].set(UXp),
+                      AXIS_X)
+
+        def lx_step(t, carry):
+            lux, ax, asq = carry
+            blk, gct = tile(t)
+            Lt = (jnp.where(gp[:, None] > gct[None, :], blk, 0.0)
+                  + (gp[:, None] == gct[None, :]).astype(jnp.float32))
+            lux = lux + jnp.matmul(Lt, jnp.take(UX, gct, axis=0),
+                                   precision=lax.Precision.HIGHEST)
+            At = seeded_entries(orig[:, None], gct[None, :], n, seed)
+            ax = ax + jnp.matmul(At, jnp.take(X, gct, axis=0),
+                                 precision=lax.Precision.HIGHEST)
+            return lux, ax, asq + jnp.sum(At * At)
+
+        z = pvary(jnp.zeros((Ml, k), jnp.float32), axes)
+        lux, ax, asq = lax.fori_loop(
+            0, Ntl, lx_step,
+            (z, z, pvary(jnp.zeros((), jnp.float32), axes)))
+        R = lax.psum(ax - lux, AXIS_Y)
+        rss = lax.psum(jnp.sum(R * R), AXIS_X)
+        ass = lax.psum(asq, (AXIS_X, AXIS_Y))
+        # identical across y and z already; pmax satisfies replication
+        return (lax.pmax(lax.pmax(rss, AXIS_Y), AXIS_Z),
+                lax.pmax(ass, AXIS_Z))
+
+    fn = shard_map(
+        device_fn,
+        mesh=mesh,
+        in_specs=(P(AXIS_X, AXIS_Y, None, None), P(), P()),
+        out_specs=(P(), P()),
     )
     return jax.jit(fn)

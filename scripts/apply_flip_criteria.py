@@ -18,7 +18,7 @@ config except the flipped knob):
   2. update='block' likewise.
   3. (historical) swap='dma' was decided only by its staged probe; the
      kernel was deleted unadopted in round 4 when the chip never
-     recovered (docs/ROUND4.md) — any dma rows in old logs are ignored.
+     recovered — any dma rows in old logs are ignored.
   4. panel_chunk=12288 as a bench-local override if it survives + wins.
 
 Output: a decision per criterion (ADOPT / KEEP / NO-DATA, with the
@@ -214,8 +214,7 @@ def main(argv=None) -> int:
     dma = [r for r in records if r.get("swap") == "dma"]
     if dma:
         print(f"note: {len(dma)} swap=dma rows in the logs are historical "
-              "— the kernel was deleted unadopted in round 4 "
-              "(docs/ROUND4.md)")
+              "— the kernel was deleted unadopted in round 4")
 
     best = _best(records)  # LU only: the emitted rule is an LU rule
     if best:

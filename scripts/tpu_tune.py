@@ -12,7 +12,7 @@ the levers:
   - v: tile size (election work ~ N^2 v; GEMM efficiency grows with v).
 
 Prints one line per config: GFLOP/s + an on-device or host residual.
-Skips instead of hanging when the chip is unresponsive (see bench.py).
+Exits at once when JAX's default device is not a TPU.
 
 Usage:
     python scripts/tpu_tune.py [-N 32768] [--reps 2] [--algo lu]
@@ -34,31 +34,34 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 def _qr_residual_on_device(Qs, Rs, geom):
     """Blockwise ||Q R - A||_F / ||A||_F on the chip for 1x1x1-mesh QR
-    outputs (the bench._ssq_blocks pattern: strips keep peak HBM at
-    Q + R + O(strip) while A strips are regenerated via bench._make_n,
-    bit-identical to the factored input)."""
+    outputs (the `validation.lu_residual_strips` pattern: strips keep
+    peak HBM at Q + R + O(strip) while A strips are regenerated by
+    `validation.seeded_entries`, bit-identical to the factored input)."""
     import math
 
     import jax
     import jax.numpy as jnp
     from jax import lax
 
-    import bench as bench_mod
+    from conflux_tpu.validation import seeded_entries
 
     n = geom.M
     Q = jnp.asarray(Qs)[0, 0]
     R = jnp.triu(jnp.asarray(Rs)[0, 0][:n])
-    blk = math.gcd(n, bench_mod.RES_BLOCK)
+    blk = math.gcd(n, 4096)
 
     @jax.jit
     def ssq(Q, R):
-        A = bench_mod._make_n(n)[0, 0]
+        rows = jnp.arange(n, dtype=jnp.int32)
         total = jnp.zeros((), jnp.float32)
+        asq = jnp.zeros((), jnp.float32)
         for i in range(0, n, blk):
+            Ai = seeded_entries(rows[i:i + blk, None], rows[None, :], n, 0)
             Ri = jnp.matmul(Q[i : i + blk], R,
-                            precision=lax.Precision.HIGHEST) - A[i : i + blk]
+                            precision=lax.Precision.HIGHEST) - Ai
             total = total + jnp.sum(Ri * Ri)
-        return total, jnp.sum(A * A)
+            asq = asq + jnp.sum(Ai * Ai)
+        return total, asq
 
     rss, ass = ssq(Q, R)
     return float(jnp.sqrt(rss) / jnp.sqrt(ass))
@@ -108,9 +111,8 @@ def main() -> None:
     if args.update != "segments" and args.algo != "lu":
         ap.error("--update applies to --algo lu only")
 
-    # validate configs BEFORE the device probe: a malformed flag must
-    # error in milliseconds, not after a (possibly wedged-chip) probe
-    # sequence. segs_arg is the same RxC grammar the miniapps use.
+    # validate configs BEFORE touching the device: a malformed flag must
+    # error in milliseconds. segs_arg is the same RxC grammar the miniapps use.
     from conflux_tpu.cli.common import segs_arg
 
     prec_names = ("high", "highest")
@@ -140,10 +142,9 @@ def main() -> None:
             if len(parts) > 5:
                 ap.error(f"config {c!r}: the 6th (swap) field was removed "
                          "in round 4 — the DMA swap kernel was deleted "
-                         "unadopted (docs/ROUND4.md)")
+                         "unadopted")
             if args.algo != "lu" and tree != "pairwise":
-                # known at parse time: do not burn a (possibly wedged)
-                # device probe before saying so
+                # known at parse time: say so before touching the device
                 ap.error(f"config {c!r}: the tree field is LU-only "
                          f"(algo={args.algo})")
             if not re.fullmatch(r"\d+", chunk) or not re.fullmatch(r"\d+", v) \
@@ -163,12 +164,13 @@ def main() -> None:
     from jax import lax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    import bench as bench_mod
+    from conflux_tpu import cache, validation
+    from conflux_tpu.cli.common import require_tpu
     from conflux_tpu.geometry import CholeskyGeometry, Grid3, LUGeometry
     from conflux_tpu.parallel.mesh import AXIS_X, AXIS_Y, make_mesh
 
-    bench_mod._enable_compile_cache()
-    bench_mod._probe_device()
+    require_tpu()
+    cache.enable_persistent_cache()
 
     N = args.N
     grid = Grid3(1, 1, 1)
@@ -230,13 +232,12 @@ def main() -> None:
                         **seg_kw)
 
                 def make(geom=geom):
-                    # bench's generator, not a copy: the residual oracle
-                    # regenerates A through the same function, so the two
-                    # must produce the bit-identical matrix
-                    return jax.device_put(bench_mod._make_n(geom.M), sharding)
+                    # the residual oracle regenerates A through the same
+                    # seeded entries, so the two are the same matrix
+                    return validation.seeded_shards(geom, mesh, 0)
 
                 def residual(out, aux):
-                    return bench_mod._residual_on_device(out[0, 0], aux)
+                    return validation.lu_residual_strips(out[0, 0], aux, 0)
 
             elif args.algo == "cholesky":
                 from conflux_tpu.cholesky.distributed import (
@@ -275,7 +276,7 @@ def main() -> None:
                         lookahead=args.lookahead, **seg_kw)
 
                 def make(geom=geom):
-                    return jax.device_put(bench_mod._make_n(geom.M), sharding)
+                    return validation.seeded_shards(geom, mesh, 0)
 
                 def residual(out, aux, geom=geom):
                     return _qr_residual_on_device(out, aux, geom)

@@ -293,3 +293,70 @@ def test_qr_auto_empty_mode_reports_no_knobs(capsys):
     assert "_auto_ (no auto-tunable knobs for this mode)" in out
     assert "(all knobs pinned)" not in out
     assert "_auto_provenance_" not in out
+
+
+def test_platform_tpu_off_tpu_fails_clearly():
+    """`--platform tpu` never quietly becomes a CPU run."""
+    with pytest.raises(SystemExit, match="not a TPU"):
+        conflux_miniapp.main(["--platform", "tpu", "-N", "64", "-b", "16",
+                              "-r", "1"])
+
+
+def _chip_smoke():
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chip_smoke.py")
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("phase", ["lu", "lu_mesh", "cholesky", "serving",
+                                   "pallas"])
+def test_chip_smoke_phases_tiny(phase, capsys):
+    """Every phase of chip_smoke.py, at a size the test picks, on the CPU:
+    its line prints PASS under the phase's bound."""
+    import jax
+
+    from conflux_tpu.geometry import Grid3
+
+    cs = _chip_smoke()
+    devs = jax.devices()
+    if phase == "lu":
+        cs.phase_lu(devs, 3, n=512, v=128)
+    elif phase == "lu_mesh":
+        cs.phase_lu(devs, 3, n=512, v=64, grid=Grid3(2, 1, 2), exact=False)
+    elif phase == "cholesky":
+        cs.phase_cholesky(devs, 3, n=256, v=64)
+    elif phase == "serving":
+        cs.phase_serving(3, fleet=8, n=64, v=32)
+    else:
+        cs.phase_pallas(3, sessions=4, n=64, panel_rows=256,
+                        require_mosaic=False)
+    out = capsys.readouterr().out
+    assert "PASS" in out and "FAIL" not in out, out
+
+
+@pytest.mark.parametrize("where", ["repo", "alone"])
+def test_chip_smoke_off_chip_exits_nonzero(where, tmp_path):
+    """Off the chip, or without the rest of the repo, the script fails
+    and never prints a result."""
+    import os
+    import shutil
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cwd = root
+    if where == "alone":
+        shutil.copy(os.path.join(root, "chip_smoke.py"), tmp_path)
+        cwd = str(tmp_path)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("PYTHONPATH", None)
+    out = subprocess.run([sys.executable, "chip_smoke.py"], cwd=cwd, env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode != 0
+    assert '"ok"' not in out.stdout

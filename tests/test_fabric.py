@@ -537,6 +537,27 @@ def test_processhost_rejects_unknown_wire():
         fabric.ProcessHost("hz", "/tmp/unused-hz", wire="carrier-pigeon")
 
 
+def test_processhost_second_chip_worker_refused(tmp_path, monkeypatch):
+    """One process per chip: with a live worker already free to claim
+    the TPU, a second such worker raises at start() before it spawns;
+    workers pinned to the CPU never claim it."""
+    class Live:
+        host_id = "h0"
+
+        class _proc:
+            @staticmethod
+            def poll():
+                return None
+
+    monkeypatch.setattr(fabric, "_chip_workers", [Live()])
+    h = fabric.ProcessHost("h1", str(tmp_path / "h1"), env={})
+    with pytest.raises(RuntimeError, match="one process per chip"):
+        h.start()
+    assert h._proc is None and not (tmp_path / "h1").exists()
+    assert not fabric._may_claim_chip({"JAX_PLATFORMS": "cpu"})
+    assert fabric._may_claim_chip({"JAX_PLATFORMS": "tpu,cpu"})
+
+
 class _AlwaysFullWire:
     """A WireClient stand-in whose request ring never drains — the
     shape of a torn pipe with replies that will never land. Optionally

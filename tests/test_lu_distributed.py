@@ -197,7 +197,7 @@ def test_lu_flat_tree_vmem_guard_dtype_aware():
     from conflux_tpu.ops import blas
     from conflux_tpu.parallel.mesh import make_mesh
 
-    if blas.scoped_vmem_bytes() != blas._SCOPED_VMEM_DEFAULT:
+    if blas.scoped_vmem_bytes() != blas._MODEL_VMEM_BYTES:
         pytest.skip("scoped-VMEM override active; pinned heights differ")
     grid = Grid3(1, 1, 1)
     geom = LUGeometry.create(32768, 32768, 1024, grid)

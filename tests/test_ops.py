@@ -109,6 +109,24 @@ def test_vmem_derived_ceilings_pin_v5e():
         blas.set_scoped_vmem_bytes(1000)
 
 
+@pytest.mark.parametrize("kind,want", [
+    ("TPU v5 lite", 32 << 20), ("TPU v99", None)])
+def test_scoped_vmem_tpu_kind_table(monkeypatch, kind, want):
+    """A TPU kind in the table gets its measured budget; one that is not
+    raises instead of borrowing another generation's figure."""
+    class Dev:
+        platform = "tpu"
+        device_kind = kind
+
+    monkeypatch.delenv("CONFLUX_TPU_SCOPED_VMEM_BYTES", raising=False)
+    monkeypatch.setattr(blas.jax, "devices", lambda: [Dev()])
+    if want is None:
+        with pytest.raises(ValueError, match="TPU v99"):
+            blas.scoped_vmem_bytes()
+    else:
+        assert blas.scoped_vmem_bytes() == want
+
+
 @pytest.mark.parametrize("Px", [3, 5, 7])
 def test_butterfly_zero_fill_contract_real_reducers(Px):
     """The odd-Px fold/unfold path makes EVERY rank reduce ppermute's

@@ -36,12 +36,12 @@ def test_spec_nopivot_diag_dominant():
 def _assert_no_vmem_override():
     """Default-chunk spec-vs-impl agreement holds only when the impl's
     scoped-VMEM budget equals the spec's pinned default: the spec pins
-    `_SCOPED_VMEM_DEFAULT` for host-independence while the impl honors
+    `_MODEL_VMEM_BYTES` for host-independence while the impl honors
     env/device overrides, so under an override the two would chunk (and
     can pivot) differently. Guard rather than silently diverge."""
     from conflux_tpu.ops import blas
 
-    assert blas.scoped_vmem_bytes() == blas._SCOPED_VMEM_DEFAULT, (
+    assert blas.scoped_vmem_bytes() == blas._MODEL_VMEM_BYTES, (
         "scoped-VMEM override active; default-chunk spec-vs-impl "
         "cross-validation needs an explicit shared panel_chunk")
 
