@@ -72,7 +72,7 @@ def _build(geom: CholeskyGeometry, mesh_key, precision, backend: str,
     row_bounds = ragged_segments(Ml // v, v, segs[0])
     col_bounds = ragged_segments(Nl // v, v, segs[1])
 
-    def device_fn(blk, k0=0, k_end=n_steps):
+    def cholesky_factor(blk, k0=0, k_end=n_steps):
         x = lax.axis_index(AXIS_X)
         y = lax.axis_index(AXIS_Y)
         z = lax.axis_index(AXIS_Z)
@@ -279,7 +279,7 @@ def _build(geom: CholeskyGeometry, mesh_key, precision, backend: str,
         in_specs, out_specs = (shard_spec, P(), P()), shard_spec
     else:
         in_specs, out_specs = shard_spec, shard_spec
-    fn = shard_map(device_fn, mesh=mesh, in_specs=in_specs,
+    fn = shard_map(cholesky_factor, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs)
     return jax.jit(fn, donate_argnums=(0,) if donate else ())
 

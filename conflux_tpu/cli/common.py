@@ -136,8 +136,8 @@ class WallTimer:
 def phase_profile(program, dev) -> None:
     """Per-phase device-time table for a distributed hot loop (the
     reference's per-step semiprof table, `README.md:120-165`): one extra
-    run under `jax.profiler.trace`, joined with the compiled program's
-    named-scope metadata by `profiler.phase_table`."""
+    run under `jax.profiler.trace`, attributed by the compiled program's
+    own scopes (`profiler.phase_table`)."""
     import tempfile
 
     from conflux_tpu import profiler
@@ -145,13 +145,15 @@ def phase_profile(program, dev) -> None:
     comp = program.lower(dev).compile()
     with tempfile.TemporaryDirectory(prefix="conflux-phases-") as trace_dir:
         with profiler.trace(trace_dir):
-            out = comp(dev)
-            sync(out[0] if isinstance(out, tuple) else out)
+            with profiler.region("profile.launch"):
+                out = comp(dev)
+            with profiler.region("profile.wait"):
+                sync(out[0] if isinstance(out, tuple) else out)
         try:
             profiler.phase_table(trace_dir, comp.as_text())
-        except (ImportError, FileNotFoundError, ValueError) as e:
-            # CPU runs have no device plane; the proto reader needs the
-            # baked tensorflow package — the host-region report still prints
+        except (FileNotFoundError, ValueError) as e:
+            # a CPU run has no device plane to read; the host-region
+            # report still prints
             print(f"(no device phase table: {e})")
 
 

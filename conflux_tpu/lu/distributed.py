@@ -109,7 +109,7 @@ def _build(geom: LUGeometry, mesh_key, precision, backend: str,
     row_segs = ragged_segments(geom.Mtl, v, segs[0])
     col_segs = ragged_segments(geom.Ntl, v, segs[1])
 
-    def device_fn(blk, orig_blk=None, k0=0, k_end=n_steps):
+    def lu_factor(blk, orig_blk=None, k0=0, k_end=n_steps):
         x = lax.axis_index(AXIS_X)
         y = lax.axis_index(AXIS_Y)
         z = lax.axis_index(AXIS_Z)
@@ -252,8 +252,6 @@ def _build(geom: LUGeometry, mesh_key, precision, backend: str,
             li = ((k // Px) * v).astype(jnp.int32)
             i0 = jnp.zeros((), jnp.int32)
             z0 = z == 0
-            U00 = jnp.triu(lu00)
-            L00 = blas.unit_lower(lu00)
 
             # ---- LAPACK-style row swaps (ref push_pivots_up, step 2) ----- #
             # winners move into the step's diagonal block (positions
@@ -317,7 +315,9 @@ def _build(geom: LUGeometry, mesh_key, precision, backend: str,
                 disp_vals = jnp.where(z0, Drows.astype(dtype),
                                       jnp.zeros((), dtype))
                 # XLA's per-row scatter loop (~10 ms/step at v=1024,
-                # N=32768 — the "other" phase-table bucket). A pipelined
+                # N=32768). Its ops carry no op_name metadata;
+                # `profiler.scope_map` credits them to this scope through
+                # the loop instruction that calls them. A pipelined
                 # Pallas row-DMA alternative existed rounds 3-4 behind
                 # swap='dma' but was deleted unadopted per the
                 # pre-decided criterion (docs/ROUND3.md #3: hardware A/B
@@ -347,6 +347,7 @@ def _build(geom: LUGeometry, mesh_key, precision, backend: str,
                 return ((chi - 1) // v) * Py + y > k
 
             with jax.named_scope("step4_dtrsm"):
+                U00 = jnp.triu(lu00)
                 pieces = []
                 for rlo, rhi in row_segs:
                     rm = row_live[rlo:rhi]
@@ -365,6 +366,7 @@ def _build(geom: LUGeometry, mesh_key, precision, backend: str,
             col_trail = ctile > k  # (Nl,)
             Prows_c = Prows.astype(cdtype)
             with jax.named_scope("step5_dtrsm"):
+                L00 = blas.unit_lower(lu00)
                 pieces = []
                 for clo, chi in col_segs:
                     pieces.append(lax.cond(
@@ -568,20 +570,23 @@ def _build(geom: LUGeometry, mesh_key, precision, backend: str,
                 k0, k_end, body_la, (Aloc, orig0, panel0, lu000, wpos0))
         else:
             Aloc, orig = lax.fori_loop(k0, k_end, body, (Aloc, orig0))
-        # all factors live on layer 0; psum makes the output z-replicated
-        Aout = lax.psum(Aloc, AXIS_Z)
-        # assemble the permutation: original row id at every global position
-        perm = jnp.zeros((Mcap,), jnp.int32).at[gp].set(orig)
-        perm = lax.psum(perm, AXIS_X)
-        # identical on every device already; pmax re-establishes replication
-        # for the out_spec
-        perm = lax.pmax(perm, (AXIS_Y, AXIS_Z))
-        if orig_blk is None:
-            return Aout[None, None], perm
-        # resumable form: the row-origin state rides along (replicated over
-        # y/z by pmax — every y/z holds the same x-row's state)
-        orig_out = lax.pmax(orig, (AXIS_Y, AXIS_Z))
-        return Aout[None, None], orig_out[None], perm
+        with jax.named_scope("step8_perm"):
+            # all factors live on layer 0; psum makes the output
+            # z-replicated
+            Aout = lax.psum(Aloc, AXIS_Z)
+            # assemble the permutation: original row id at every global
+            # position
+            perm = jnp.zeros((Mcap,), jnp.int32).at[gp].set(orig)
+            perm = lax.psum(perm, AXIS_X)
+            # identical on every device already; pmax re-establishes
+            # replication for the out_spec
+            perm = lax.pmax(perm, (AXIS_Y, AXIS_Z))
+            if orig_blk is None:
+                return Aout[None, None], perm
+            # resumable form: the row-origin state rides along (replicated
+            # over y/z by pmax — every y/z holds the same x-row's state)
+            orig_out = lax.pmax(orig, (AXIS_Y, AXIS_Z))
+            return Aout[None, None], orig_out[None], perm
 
     shard_spec = P(AXIS_X, AXIS_Y, None, None)
     if resumable:
@@ -589,8 +594,8 @@ def _build(geom: LUGeometry, mesh_key, precision, backend: str,
         out_specs = (shard_spec, P(AXIS_X, None), P())
     else:
         in_specs, out_specs = shard_spec, (shard_spec, P())
-    fn = shard_map(device_fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs)
+    fn = shard_map(lu_factor, mesh=mesh, in_specs=in_specs,
+                   out_specs=out_specs)
     return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
 

@@ -2,24 +2,31 @@
 (`libs/semiprof/include/semiprof/semiprof.hpp:38-52`) and the PE/PL/PP/PC
 macro shims (`src/conflux/lu/profiler.hpp`, `cholesky/CholeskyProfiler.h`).
 
-`region(name)` is both a context manager and a decorator; it wraps the body
-in `jax.named_scope` (so regions show up in XLA/`jax.profiler` traces under
-the same names) and accumulates host-side wall time and call counts.
-`report()` prints a semiprof-style table sorted by total time; `clear()`
-resets. Region names follow the reference's step vocabulary
-(`step0_reduce`, `step1_pivoting`, ..., `conflux_opt.hpp:635,777,1346`).
+Host side: `region(name)` is both a context manager and a decorator; it
+accumulates host wall time and call counts, and emits a
+`jax.profiler.TraceAnnotation`, so the region lands on the trace's host
+planes on the clock the device events are put on. `report()` prints a
+semiprof-style table sorted by total time; `clear()` resets.
 
-For on-device timing of jitted code use `trace(logdir)` which forwards to
-`jax.profiler.trace` (XPlane output readable in TensorBoard/XProf).
+Device side: the jitted programs name their phases with `jax.named_scope`
+in the reference's step vocabulary (`step0_reduce` ... `step8_perm`,
+`reduceA11` ..., `solve_fwd` ..., `conflux_opt.hpp:635,777,1346`), and each
+shard_map program carries its own name (`jit_lu_factor`, `jit_lu_solve`,
+...). `scope_map(hlo_text)` credits every instruction of a compiled
+program to a phase; `phase_table(trace_dir, hlo_text)` joins it with a
+`trace(logdir)` capture (`jax.profiler.trace`, read back with
+`jax.profiler.ProfileData`) into a per-phase device-time table.
 """
 
 from __future__ import annotations
 
 import contextlib
 import functools
+import re
 import threading
 import time
 from collections import defaultdict
+from typing import NamedTuple
 
 import jax
 
@@ -30,18 +37,11 @@ import jax
 _PROF_LOCK = threading.Lock()
 _times: dict[str, float] = defaultdict(float)    # guarded-by: _PROF_LOCK
 _counts: dict[str, int] = defaultdict(int)       # guarded-by: _PROF_LOCK
-_enabled = True
 
 # set by conflux_tpu.analysis.lockcheck while a watch() is active: the
 # hook observes which locks are held when a dispatch region is entered.
 # One attribute read per region when inactive.
 _dispatch_hook = None
-
-
-def enable(on: bool = True) -> None:
-    """Compile-time switch analog (reference CONFLUX_WITH_PROFILING)."""
-    global _enabled
-    _enabled = on
 
 
 # ---------------------------------------------------------------------- #
@@ -78,16 +78,15 @@ def compile_count() -> int:
 
 @contextlib.contextmanager
 def region(name: str):
-    """Profiled named scope: `with profiler.region('step1_pivoting'): ...`"""
+    """Profiled host region: `with profiler.region('serve.solve'): ...`.
+    Counts the call and its wall time, and emits a host span of the same
+    name into an active `jax.profiler` trace. It writes nothing into the
+    metadata of programs traced inside it."""
     hook = _dispatch_hook
     if hook is not None:
         hook(name)
-    if not _enabled:
-        with jax.named_scope(name):
-            yield
-        return
     t0 = time.perf_counter()
-    with jax.named_scope(name):
+    with jax.profiler.TraceAnnotation(name):
         yield
     dt = time.perf_counter() - t0
     with _PROF_LOCK:
@@ -636,120 +635,314 @@ class CounterWindow:
         return out
 
 
+
+
 # --------------------------------------------------------------------------- #
 # Device-side per-phase timing (the reference's per-step semiprof table)
 # --------------------------------------------------------------------------- #
 
-# LU loop scopes (step0_reduce .. step7_writes) + Cholesky loop scopes
-# (reference vocabulary: reduceA11/choleskyA00/updateA10/scatterA11/computeA11)
-_PHASE_RE = r"(step\d+_[a-z0-9]+|(?:reduce|cholesky|update|compute|scatter)A\d\d)"
+# one component of an op_name path that names a phase: the LU loop
+# (step0_reduce .. step8_perm), the Cholesky loop (reference vocabulary
+# reduceA11/choleskyA00/updateA10/scatterA11/computeA11), the mesh solves
+# (solve_perm/solve_fwd/solve_bwd) and the QR loop (qr_*)
+PHASE_RE = (r"step\d+_[a-z0-9]+|(?:reduce|cholesky|update|compute|scatter)"
+            r"A\d\d|solve_[a-z]+|qr_[a-z_]+")
+UNATTRIBUTED = "(unattributed)"
 
-# optimized-HLO "op token -> op_name metadata" line shape; shared with
-# scripts/step_profile.py's --top-other listing so the two parsers cannot
-# drift apart across jax versions
-OP_NAME_RE = r"%([\w.-]+) = .*?metadata=\{[^}]*?op_name=\"([^\"]*)\""
+_INSTR_RE = re.compile(r"\s+(?:ROOT )?%([\w.-]+) = ")
+# "<result type> <opcode>(" — the trace prints each op's name in the same
+# shape, so (token, result type, opcode) identifies an instruction across
+# programs whose tokens collide
+_HEAD_RE = re.compile(r"(.*?) ([a-z][\w-]*)\(")
+_CALLS_RE = re.compile(r"\b(?:calls|body|condition|to_apply|true_computation|"
+                       r"false_computation)=%([\w.-]+)")
+_CALL_SET_RE = re.compile(
+    r"\b(?:branch_computations|called_computations)=\{([^}]*)\}")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_TOKEN_RE = re.compile(r"%([\w.-]+)")
 
 
-def op_name_map(hlo_text: str) -> dict[str, str]:
-    """HLO op token -> op_name metadata string (empty-metadata ops absent)."""
-    import re
+class _Instr(NamedTuple):
+    comp: str          # the computation it belongs to
+    head: tuple        # (token, result type, opcode)
+    operands: list     # operand tokens
+    called: list       # computations it calls
+    op_name: str | None
 
-    return dict(re.findall(OP_NAME_RE, hlo_text))
+
+def _operand_text(text: str, start: int) -> str:
+    """The balanced parenthesised operand list opening at text[start]."""
+    depth = 0
+    for i in range(start, len(text)):
+        if text[i] == "(":
+            depth += 1
+        elif text[i] == ")":
+            depth -= 1
+            if depth == 0:
+                return text[start:i]
+    return text[start:]
 
 
-def _scope_map(hlo_text: str, phase_re: str) -> dict[str, str]:
-    """HLO op token -> phase name, from optimized-HLO `op_name` metadata.
-
-    The factorization is one jitted program, so host-side `region` timing
-    can never split the hot loop (the judge's round-1 finding). The phases
-    ARE visible on the device though: every `jax.named_scope` lands in the
-    compiled executable's per-op `metadata={op_name="..."}`, and the XPlane
-    trace records each op's device duration. Joining the two recovers a true
-    per-phase device-time table from the production program — no staged
-    sub-jits, no scheduling perturbation.
-    """
-    import re
-
-    phase = re.compile(phase_re)
-    out: dict[str, str] = {}
-    for tok, op_name in op_name_map(hlo_text).items():
-        m = phase.search(op_name)
-        if m:
-            out[tok] = m.group(1)
+def _instructions(hlo_text: str) -> dict:
+    """op token -> _Instr, in program text order, of a compiled program."""
+    out: dict = {}
+    comp = None
+    for line in hlo_text.splitlines():
+        if line and not line[0].isspace():
+            m = re.match(r"(?:ENTRY )?%([\w.-]+) ", line)
+            comp = m.group(1) if m and line.rstrip().endswith("{") else None
+            continue
+        m = _INSTR_RE.match(line)
+        if m is None or comp is None:
+            continue
+        tok, rest = m.group(1), line[m.end():]
+        hm = _HEAD_RE.match(rest)
+        ops = (_TOKEN_RE.findall(_operand_text(rest, hm.end() - 1))
+               if hm else [])
+        called = _CALLS_RE.findall(rest)
+        for group in _CALL_SET_RE.findall(rest):
+            called += _TOKEN_RE.findall(group)
+        nm = _OP_NAME_RE.search(rest)
+        out[tok] = _Instr(comp, (tok,) + (hm.groups() if hm else ("", "")),
+                          ops, called, nm.group(1) if nm else None)
     return out
 
 
-def _trace_durations(trace_dir: str) -> dict[str, float]:
-    """HLO op token -> total device time (ms) from the newest xplane.pb."""
+def _phase_of(op_name: str, phase: re.Pattern) -> str | None:
+    """The innermost component of an op_name path that names a phase."""
+    found = None
+    for part in op_name.split("/"):
+        if phase.fullmatch(part):
+            found = part
+    return found
+
+
+def scope_map(hlo_text: str, phase_re: str = PHASE_RE) -> dict[str, str]:
+    """Op token -> phase for a compiled program's text
+    (`jitted.lower(...).compile().as_text()`).
+
+    The rule, in order:
+    1. an instruction whose `op_name` metadata names a phase (a path
+       component matching `phase_re`, the innermost one) takes it;
+    2. an instruction XLA generated takes the phase of its consumers
+       within the same computation, transitively. XLA generated it when
+       it has no `op_name` at all (copies, the per-row loop a scatter
+       lowers to), when its `op_name` is a bare name and no scope path (a
+       layout copy of a parameter takes the parameter's name), or when
+       it is a clone (`.clone` in its token) whose inherited `op_name`
+       names no phase;
+    3. failing that, the phase of its operands within the same
+       computation;
+    4. failing that, the phase of the instruction that calls its
+       computation (`calls=`, `body=`, `condition=`, `to_apply=`,
+       `branch_computations=`), so a generated loop's body and condition
+       take the phase of the loop.
+    A step of rules 3 and 4 is followed by rule 2 again, and rule 4 only
+    runs where rule 3 finds nothing. Any other instruction whose `op_name`
+    names no phase is user code outside every scope: it stays out of the
+    map, and the table shows it as unattributed.
+    """
+    phase = re.compile(phase_re)
+    ins = _instructions(hlo_text)
+    out: dict[str, str] = {}
+    for tok, it in ins.items():
+        if it.op_name:
+            p = _phase_of(it.op_name, phase)
+            if p:
+                out[tok] = p
+    operands: dict[str, list] = {}
+    callers: dict[str, list] = defaultdict(list)
+    for tok, it in ins.items():
+        operands[tok] = [o for o in it.operands
+                         if o in ins and ins[o].comp == it.comp]
+        for c in it.called:
+            callers[c].append(tok)
+
+    def open_(tok):  # XLA generated, and not credited yet
+        op_name = ins[tok].op_name
+        return tok not in out and (op_name is None or "/" not in op_name
+                                   or ".clone" in tok)
+
+    def to_operands(front):  # rule 2, to a fixed point
+        while front:
+            nxt = []
+            for tok in front:
+                for o in operands[tok]:
+                    if open_(o):
+                        out[o] = out[tok]
+                        nxt.append(o)
+            front = nxt
+
+    to_operands(list(out))
+    while True:
+        new = {}
+        for tok in ins:  # rule 3, one step
+            if open_(tok):
+                src = next((o for o in operands[tok] if o in out), None)
+                if src is not None:
+                    new[tok] = out[src]
+        if not new:  # rule 4, one step
+            for tok, it in ins.items():
+                if open_(tok):
+                    src = next((c for c in callers.get(it.comp, ())
+                                if c in out), None)
+                    if src is not None:
+                        new[tok] = out[src]
+        if not new:
+            return out
+        out.update(new)
+        to_operands(list(new))
+
+
+def _load_trace(path: str):
+    """`jax.profiler.ProfileData` of the newest `*.xplane.pb` under a
+    directory, or of one (optionally gzipped) file."""
     import glob
     import os
 
-    files = sorted(
-        glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True),
-        key=os.path.getmtime,
-    )
-    if not files:
-        raise FileNotFoundError(f"no *.xplane.pb under {trace_dir}")
-    from tensorflow.tsl.profiler.protobuf import xplane_pb2  # vendored proto
+    from jax.profiler import ProfileData
 
-    xs = xplane_pb2.XSpace()
-    with open(files[-1], "rb") as f:
-        xs.ParseFromString(f.read())
-    durs: dict[str, float] = defaultdict(float)
-    for plane in xs.planes:
-        if not plane.name.startswith("/device:"):
-            continue
-        for line in plane.lines:
-            # 'XLA Modules' spans whole executables and 'Async XLA Ops'
-            # overlaps compute (DMA) — only the serial op line is the
-            # device's actual timeline
-            if line.name != "XLA Ops":
-                continue
-            # the op timeline is hierarchical: while/cond events span their
-            # body ops, so raw duration sums double-count. Credit each op
-            # its SELF time (duration minus directly nested events).
-            evs = []
-            for ev in line.events:
-                name = plane.event_metadata[ev.metadata_id].name
-                tok = name[1:].split(" ", 1)[0] if name.startswith("%") else name
-                evs.append((ev.offset_ps, ev.offset_ps + ev.duration_ps, tok))
-            evs.sort(key=lambda e: (e[0], -(e[1] - e[0])))
-            self_ps: list[float] = [e[1] - e[0] for e in evs]
-            stack: list[int] = []  # indices of currently open events
-            for i, (off, end, _tok) in enumerate(evs):
-                while stack and evs[stack[-1]][1] <= off:
-                    stack.pop()
-                if stack:  # nested: take my span out of my parent's self
-                    self_ps[stack[-1]] -= end - off
-                stack.append(i)
-            for (_off, _end, tok), s in zip(evs, self_ps):
-                durs[tok] += s / 1e9
-    if not durs:
-        raise ValueError(
-            "trace has no device op events (CPU runs have no device "
-            "plane; the phase table needs a TPU execution)")
-    return dict(durs)
+    if os.path.isdir(path):
+        files = sorted(glob.glob(os.path.join(path, "**", "*.xplane.pb"),
+                                 recursive=True), key=os.path.getmtime)
+        if not files:
+            raise FileNotFoundError(f"no *.xplane.pb under {path}")
+        path = files[-1]
+    if path.endswith(".gz"):
+        import gzip
+
+        with gzip.open(path, "rb") as f:
+            return ProfileData.from_serialized_xspace(f.read())
+    return ProfileData.from_file(path)
+
+
+def _line_events(plane, name: str) -> list:
+    """[(name, start ns, end ns)] of a plane's line, by start."""
+    for ln in plane.lines:
+        if ln.name == name:
+            return sorted(((e.name, float(e.start_ns),
+                            float(e.start_ns) + float(e.duration_ns))
+                           for e in ln.events), key=lambda e: e[1])
+    return []
+
+
+def _self_times(evs: list) -> list:
+    """Self time of each (start-sorted) event: its duration less that of
+    the events directly inside it (a while op spans its body's ops)."""
+    own = [e[2] - e[1] for e in evs]
+    order = sorted(range(len(evs)), key=lambda i: (evs[i][1], -own[i]))
+    stack: list = []
+    for i in order:
+        while stack and evs[stack[-1]][2] <= evs[i][1]:
+            stack.pop()
+        if stack:
+            own[stack[-1]] -= evs[i][2] - evs[i][1]
+        stack.append(i)
+    return own
+
+
+def _idle_gaps(ops: list, t0: float, t1: float) -> list:
+    """[(start, end)] of the intervals inside [t0, t1] no op covers."""
+    out, cur = [], t0
+    for _, s, e in ops:
+        if s > cur:
+            out.append((cur, min(s, t1)))
+        cur = max(cur, e)
+    if t1 > cur:
+        out.append((cur, t1))
+    return [g for g in out if g[1] > g[0]]
+
+
+def _trace_head(name: str) -> tuple:
+    m = re.match(r"%([\w.-]+) = ", name)
+    if m is None:
+        return (name, "", "")
+    hm = _HEAD_RE.match(name[m.end():])
+    return (m.group(1),) + (hm.groups() if hm else ("", ""))
 
 
 def phase_table(trace_dir: str, hlo_text: str,
-                phase_re: str = _PHASE_RE) -> dict[str, tuple[float, int]]:
-    """Per-phase device time {phase: (ms, ops)} for a traced jitted program.
+                phase_re: str = PHASE_RE) -> dict[str, tuple[float, int]]:
+    """Per-phase device self time {phase: (ms, ops)} of one program in a
+    trace, summed over its executions and chips.
 
-    `hlo_text` is `fn.lower(*args).compile().as_text()` of the same program
-    that ran under :func:`trace`. Ops whose scope matches no phase are
-    aggregated under '(other)'. Prints the reference-shaped table
-    (README.md:120-165) and returns the mapping.
+    `trace_dir` is what :func:`trace` wrote (or one `.xplane.pb[.gz]`);
+    `hlo_text` is `fn.lower(*args).compile().as_text()` of the program
+    that ran. Only ops inside that program's own executions on the
+    `XLA Modules` line count: an execution is the program's when its
+    module name is the program's and nearly all of its first ops are
+    among the program's instructions (token, result type, opcode), so
+    programs that share a module name, as every shard_map program did
+    before they were named, are told apart. Each op is credited its self
+    time and its phase by :func:`scope_map`; ops it leaves out are
+    '(unattributed)'. Prints the table, then the traced window's device
+    busy share and its 5 longest idle gaps, each named by the innermost
+    host span of a :func:`region` this process has timed that covers it.
+    Raises ValueError for a trace with no device ops (a CPU run).
     """
-    scope = _scope_map(hlo_text, phase_re)
-    durs = _trace_durations(trace_dir)
-    agg: dict[str, tuple[float, int]] = defaultdict(lambda: (0.0, 0))
-    for tok, ms in durs.items():
-        ph = scope.get(tok, "(other)")
-        t, n = agg[ph]
-        agg[ph] = (t + ms, n + 1)
+    import bisect
+
+    scope = scope_map(hlo_text, phase_re)
+    heads = {it.head for it in _instructions(hlo_text).values()}
+    m = re.search(r"^HloModule ([\w.-]+)", hlo_text, re.M)
+    module = m.group(1) if m else ""
+    pd = _load_trace(trace_dir)
+    chips = [p for p in pd.planes if p.name.startswith("/device:")
+             and not p.name.startswith("/device:CUSTOM")]
+    chips = [p for p in chips if _line_events(p, "XLA Ops")]
+    if not chips:
+        raise ValueError("trace has no device op events (a CPU run has no "
+                         "device plane; the table needs a TPU execution)")
+    agg: dict = defaultdict(lambda: [0.0, 0])
+    runs = 0
+    for plane in chips:
+        ops = _line_events(plane, "XLA Ops")
+        own = _self_times(ops)
+        starts = [e[1] for e in ops]
+        for name, t0, t1 in _line_events(plane, "XLA Modules"):
+            lo = bisect.bisect_left(starts, t0)
+            hi = bisect.bisect_right(starts, t1)
+            probe = [_trace_head(ops[i][0])
+                     for i in range(lo, min(hi, lo + 64))]
+            if (name.split("(")[0] != module or not probe
+                    or 10 * sum(h in heads for h in probe) < 9 * len(probe)):
+                continue
+            runs += 1
+            for i in range(lo, hi):
+                if ops[i][2] <= t1:
+                    row = agg[scope.get(_trace_head(ops[i][0])[0],
+                                        UNATTRIBUTED)]
+                    row[0] += own[i] / 1e6
+                    row[1] += 1
     total = sum(t for t, _ in agg.values()) or 1.0
-    lines = [f"{'PHASE':<24}{'OPS':>8}{'DEVICE ms':>14}{'%':>8}"]
+    lines = [f"{module}: {runs} execution(s) on {len(chips)} chip(s)",
+             f"{'PHASE':<24}{'OPS':>8}{'DEVICE ms':>14}{'%':>8}"]
     for ph, (t, n) in sorted(agg.items(), key=lambda kv: -kv[1][0]):
         lines.append(f"{ph:<24}{n:>8}{t:>14.3f}{100 * t / total:>8.1f}")
+    lines += _window_lines(pd, _line_events(chips[0], "XLA Ops"))
     print("\n".join(lines))
-    return dict(agg)
+    return {ph: (t, n) for ph, (t, n) in agg.items()}
+
+
+def _window_lines(pd, ops: list) -> list:
+    """The busy share of the traced window (the device's first op to its
+    last, widened to the program's host spans) and its 5 longest idle
+    gaps, each named by the innermost program span that covers it."""
+    spans = set(_snapshot()[0])
+    host = [(e.name, float(e.start_ns), float(e.start_ns + e.duration_ns))
+            for p in pd.planes if p.name.startswith("/host:")
+            for ln in p.lines for e in ln.events if e.name in spans]
+    t0 = min([ops[0][1]] + [s for _, s, _ in host])
+    t1 = max([max(e for _, _, e in ops)] + [e for _, _, e in host])
+    gaps = _idle_gaps(ops, t0, t1)
+    idle = sum(e - s for s, e in gaps)
+    out = [f"device busy {100 * (1 - idle / (t1 - t0)):.3f}% of the traced "
+           f"window ({(t1 - t0) / 1e6:.3f} ms); longest idle gaps:"]
+    for s, e in sorted(gaps, key=lambda g: g[0] - g[1])[:5]:
+        best, key = "(no program span)", None
+        for name, hs, he in host:
+            cover = min(e, he) - max(s, hs)
+            if cover > 0 and (key is None or (cover, hs - he) > key):
+                best, key = name, (cover, hs - he)
+        out.append(f"  {(e - s) / 1e6:10.3f} ms  {best}")
+    return out

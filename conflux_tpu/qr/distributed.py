@@ -102,7 +102,7 @@ def _build(mesh_key, algo: str, shape, dtype_name: str, chunk: int,
     dtype = jnp.dtype(dtype_name)
     prec = blas.matmul_precision()
 
-    def device_fn(blk):
+    def qr_tall_factor(blk):
         A = blk[0].astype(blas.compute_dtype(dtype))
         if algo == "tsqr":
             Q, R = _two_pass_tsqr(A, Px, chunk, passes, prec, tree=tree)
@@ -122,7 +122,7 @@ def _build(mesh_key, algo: str, shape, dtype_name: str, chunk: int,
         R = replicate(R, tuple(mesh.axis_names))
         return Q.astype(dtype)[None], R.astype(dtype)
 
-    fn = shard_map(device_fn, mesh=mesh,
+    fn = shard_map(qr_tall_factor, mesh=mesh,
                        in_specs=P(AXIS_X, None, None),
                        out_specs=(P(AXIS_X, None, None), P()))
     return jax.jit(fn)
@@ -277,7 +277,7 @@ def _build_full(geom, mesh_key, precision, backend: str, chunk: int,
         # branch output types match the mask-dependent compute branches
         return pvary(val, (AXIS_X, AXIS_Y, AXIS_Z))
 
-    def device_fn(blk, rblk=None, k0=0, k_end=n_steps):
+    def qr_factor(blk, rblk=None, k0=0, k_end=n_steps):
         x = lax.axis_index(AXIS_X)
         y = lax.axis_index(AXIS_Y)
         z = lax.axis_index(AXIS_Z)
@@ -548,7 +548,7 @@ def _build_full(geom, mesh_key, precision, backend: str, chunk: int,
         in_specs = (shard_spec, shard_spec, P(), P())
     else:
         in_specs = shard_spec
-    fn = shard_map(device_fn, mesh=mesh, in_specs=in_specs,
+    fn = shard_map(qr_factor, mesh=mesh, in_specs=in_specs,
                        out_specs=(shard_spec, shard_spec))
     # resumable mode donates the O(N^2) R state too — unlike LU's O(M)
     # orig map, holding input and output R simultaneously is matrix-sized

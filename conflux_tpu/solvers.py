@@ -117,10 +117,8 @@ def lu_solve_distributed(shards, perm, geom, mesh, b) -> jax.Array:
     shape, replicated.
     """
     _check_solve_rhs(geom, b)
-    b2, squeeze = _as_2d(jnp.asarray(b, blas.compute_dtype(shards.dtype)))
     fn = _build_lu_solve(geom, mesh_cache_key(mesh))
-    x = fn(shards, jnp.asarray(perm, jnp.int32), b2)
-    return x[:, 0] if squeeze else x
+    return fn(shards, jnp.asarray(perm), jnp.asarray(b))
 
 
 @functools.lru_cache(maxsize=16)
@@ -141,11 +139,13 @@ def _build_lu_solve(geom, mesh_key):
         x_ = lax.axis_index(AXIS_X)
         y_ = lax.axis_index(AXIS_Y)
         dtype = blas.compute_dtype(blk.dtype)
-        Aloc = blk[0, 0].astype(dtype)  # z-replicated factors, pivoted order
-        bp = b.astype(dtype)[perm]  # rhs in pivoted row order
-
-        lc = jnp.arange(Nl, dtype=jnp.int32)
-        gcol = ((lc // v) * Py + y_) * v + (lc % v)
+        with jax.named_scope("solve_load"):
+            # z-replicated factors, pivoted order
+            Aloc = blk[0, 0].astype(dtype)
+            lc = jnp.arange(Nl, dtype=jnp.int32)
+            gcol = ((lc // v) * Py + y_) * v + (lc % v)
+        with jax.named_scope("solve_perm"):
+            bp = b[perm]  # rhs in pivoted row order
 
         nrhs = bp.shape[1]
         i0 = jnp.zeros((), jnp.int32)
@@ -161,7 +161,8 @@ def _build_lu_solve(geom, mesh_key):
             yk = blas.trsm_left_lower_unit(blas.unit_lower(diag), bk - s)
             return lax.dynamic_update_slice(yv, yk, (kv, i0))
 
-        yv = lax.fori_loop(0, n, fwd, jnp.zeros((geom.N, nrhs), dtype))
+        with jax.named_scope("solve_fwd"):
+            yv = lax.fori_loop(0, n, fwd, jnp.zeros((geom.N, nrhs), dtype))
 
         def bwd(i, xv):
             k = n - 1 - i
@@ -175,20 +176,32 @@ def _build_lu_solve(geom, mesh_key):
             xk = blas.trsm_left_upper(jnp.triu(diag), yk - s)
             return lax.dynamic_update_slice(xv, xk, (kv, i0))
 
-        xv = lax.fori_loop(0, n, bwd, jnp.zeros((geom.N, nrhs), dtype))
-        # replicated by construction (pure collectives); replicate (a
-        # complex-safe pmax) satisfies the
-        # out_spec's replication check
-        from conflux_tpu.parallel.mesh import replicate
-        return replicate(xv, (AXIS_X, AXIS_Y, AXIS_Z))
+        with jax.named_scope("solve_bwd"):
+            xv = lax.fori_loop(0, n, bwd, jnp.zeros((geom.N, nrhs), dtype))
+            # replicated by construction (pure collectives); replicate (a
+            # complex-safe pmax) satisfies the out_spec's replication check
+            from conflux_tpu.parallel.mesh import replicate
+            return replicate(xv, (AXIS_X, AXIS_Y, AXIS_Z))
 
-    fn = shard_map(
+    sharded = shard_map(
         device_fn,
         mesh=mesh,
         in_specs=(P(AXIS_X, AXIS_Y, None, None), P(), P()),
         out_specs=P(),
     )
-    return jax.jit(fn)
+
+    def lu_solve(blk, perm, b):
+        # the (N,) <-> (N, 1) reshapes and the casts run inside the one
+        # program: a solve is a single launch
+        with jax.named_scope("solve_perm"):
+            b2 = b.astype(blas.compute_dtype(blk.dtype))
+            b2 = b2[:, None] if b.ndim == 1 else b2
+            perm = perm.astype(jnp.int32)
+        x = sharded(blk, perm, b2)
+        with jax.named_scope("solve_bwd"):
+            return x[:, 0] if b.ndim == 1 else x
+
+    return jax.jit(lu_solve)
 
 
 def cholesky_solve_distributed(shards, geom, mesh, b) -> jax.Array:
@@ -220,11 +233,12 @@ def _build_cholesky_solve(geom, mesh_key):
     v, Px, Py = geom.v, geom.grid.Px, geom.grid.Py
     Ml, Nl, n = geom.Ml, geom.Nl, geom.Kappa
 
-    def device_fn(blk, b):
+    def cholesky_solve(blk, b):
         x_ = lax.axis_index(AXIS_X)
         y_ = lax.axis_index(AXIS_Y)
         dtype = blas.compute_dtype(blk.dtype)
-        Aloc = blk[0, 0].astype(dtype)  # z-replicated factors, lower = L
+        with jax.named_scope("solve_load"):
+            Aloc = blk[0, 0].astype(dtype)  # z-replicated factors, lower = L
         b = b.astype(dtype)
 
         lr = jnp.arange(Ml, dtype=jnp.int32)
@@ -246,7 +260,8 @@ def _build_cholesky_solve(geom, mesh_key):
             yk = blas.trsm_left_lower(jnp.tril(diag), bk - s)
             return lax.dynamic_update_slice(yv, yk, (kv, i0))
 
-        yv = lax.fori_loop(0, n, fwd, jnp.zeros((geom.N, nrhs), dtype))
+        with jax.named_scope("solve_fwd"):
+            yv = lax.fori_loop(0, n, fwd, jnp.zeros((geom.N, nrhs), dtype))
 
         def bwd(i, xv):
             k = n - 1 - i
@@ -273,12 +288,13 @@ def _build_cholesky_solve(geom, mesh_key):
             xk = blas.trsm_left_lower_t(jnp.tril(diag), yk - s)
             return lax.dynamic_update_slice(xv, xk, (kv, i0))
 
-        xv = lax.fori_loop(0, n, bwd, jnp.zeros((geom.N, nrhs), dtype))
-        from conflux_tpu.parallel.mesh import replicate
-        return replicate(xv, (AXIS_X, AXIS_Y, AXIS_Z))
+        with jax.named_scope("solve_bwd"):
+            xv = lax.fori_loop(0, n, bwd, jnp.zeros((geom.N, nrhs), dtype))
+            from conflux_tpu.parallel.mesh import replicate
+            return replicate(xv, (AXIS_X, AXIS_Y, AXIS_Z))
 
     fn = shard_map(
-        device_fn,
+        cholesky_solve,
         mesh=mesh,
         in_specs=(P(AXIS_X, AXIS_Y, None, None), P()),
         out_specs=P(),
@@ -755,14 +771,14 @@ def _build_qtb(mesh_key, cdtype_name: str):
 
     from conflux_tpu.parallel.mesh import replicate
 
-    def device_fn(qblk, bblk):
+    def qr_qtb(qblk, bblk):
         c = lax.psum(
             jnp.matmul(qblk[0].astype(cdtype).conj().T, bblk[0],
                        precision=lax.Precision.HIGHEST), AXIS_X)
         return replicate(c, tuple(mesh.axis_names))
 
     return jax.jit(shard_map(
-        device_fn, mesh=mesh,
+        qr_qtb, mesh=mesh,
         in_specs=(P(AXIS_X, None, None), P(AXIS_X, None, None)),
         out_specs=P()))
 
@@ -934,7 +950,7 @@ def _build_qr_lstsq(geom, mesh_key):
     Ml, Nl = geom.Ml, geom.Nl
     n = geom.Nt  # R row tiles to substitute
 
-    def device_fn(Qblk, Rblk, b):
+    def qr_lstsq(Qblk, Rblk, b):
         x_ = lax.axis_index(AXIS_X)
         y_ = lax.axis_index(AXIS_Y)
         dtype = blas.compute_dtype(Qblk.dtype)
@@ -977,7 +993,7 @@ def _build_qr_lstsq(geom, mesh_key):
         return replicate(xv, (AXIS_X, AXIS_Y, AXIS_Z))
 
     fn = shard_map(
-        device_fn,
+        qr_lstsq,
         mesh=mesh,
         in_specs=(P(AXIS_X, AXIS_Y, None, None),
                   P(AXIS_X, AXIS_Y, None, None), P()),

@@ -81,7 +81,7 @@ def _build_lu_residual(geom, mesh_key):
     Ml, Nl = geom.Ml, geom.Nl
     Mt, Nt = geom.Mt, geom.Nt
 
-    def device_fn(Ablk, LUblk, perm):
+    def lu_residual_sums(Ablk, LUblk, perm):
         x = lax.axis_index(AXIS_X)
         y = lax.axis_index(AXIS_Y)
         Aloc = Ablk[0, 0]
@@ -146,7 +146,7 @@ def _build_lu_residual(geom, mesh_key):
         return (lax.pmax(rss, AXIS_Z), lax.pmax(ass, AXIS_Z))
 
     fn = shard_map(
-        device_fn,
+        lu_residual_sums,
         mesh=mesh,
         in_specs=(P(AXIS_X, AXIS_Y, None, None),
                   P(AXIS_X, AXIS_Y, None, None), P()),
@@ -195,7 +195,7 @@ def _build_cholesky_residual(geom, mesh_key):
     Ml, Nl = geom.Ml, geom.Nl
     Nt = geom.Kappa  # tile columns == supersteps
 
-    def device_fn(Ablk, Lblk):
+    def cholesky_residual_sums(Ablk, Lblk):
         x = lax.axis_index(AXIS_X)
         y = lax.axis_index(AXIS_Y)
         Aloc = Ablk[0, 0]
@@ -240,7 +240,7 @@ def _build_cholesky_residual(geom, mesh_key):
         return (lax.pmax(rss, AXIS_Z), lax.pmax(ass, AXIS_Z))
 
     fn = shard_map(
-        device_fn,
+        cholesky_residual_sums,
         mesh=mesh,
         in_specs=(P(AXIS_X, AXIS_Y, None, None),
                   P(AXIS_X, AXIS_Y, None, None)),
@@ -319,7 +319,7 @@ def _build_qr_residual(geom, mesh_key):
     v, Px, Py = geom.v, geom.grid.Px, geom.grid.Py
     Ml, Nl, Nt = geom.Ml, geom.Nl, geom.Nt
 
-    def device_fn(Ablk, Qblk, Rblk):
+    def qr_residual_sums(Ablk, Qblk, Rblk):
         x = lax.axis_index(AXIS_X)
         y = lax.axis_index(AXIS_Y)
         from conflux_tpu.ops import blas as _blas
@@ -373,7 +373,7 @@ def _build_qr_residual(geom, mesh_key):
                 lax.pmax(oss, AXIS_Z))
 
     fn = shard_map(
-        device_fn,
+        qr_residual_sums,
         mesh=mesh,
         in_specs=(P(AXIS_X, AXIS_Y, None, None),
                   P(AXIS_X, AXIS_Y, None, None),
@@ -554,7 +554,7 @@ def _build_lu_projected(geom, mesh_key, seed: int, k: int):
     Ml, Nl, n = geom.Ml, geom.Nl, geom.N
     Ntl = Nl // v
 
-    def device_fn(LUblk, perm, X):
+    def lu_projected_sums(LUblk, perm, X):
         x = lax.axis_index(AXIS_X)
         y = lax.axis_index(AXIS_Y)
         LUloc = LUblk[0, 0].astype(jnp.float32)
@@ -608,7 +608,7 @@ def _build_lu_projected(geom, mesh_key, seed: int, k: int):
                 lax.pmax(ass, AXIS_Z))
 
     fn = shard_map(
-        device_fn,
+        lu_projected_sums,
         mesh=mesh,
         in_specs=(P(AXIS_X, AXIS_Y, None, None), P(), P()),
         out_specs=(P(), P()),

@@ -1,7 +1,7 @@
 """Single-chip tuning sweep (run on real TPU) for all three cores.
 
 Times the distributed program for the selected algorithm at bench scale
-across the knobs the phase table (scripts/step_profile.py) identified as
+across the knobs the phase table (`conflux_miniapp --profile`) identified as
 the levers:
 
   - matmul precision: HIGHEST (6-pass f32) vs HIGH (bf16x3) for the
