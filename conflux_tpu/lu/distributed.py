@@ -78,19 +78,35 @@ _GRI_SENTINEL = np.iinfo(np.int32).max
 # the derived values pin to 8192/4096).
 
 
+def _swap_kernel(platform: str, dtype, geom: LUGeometry) -> bool:
+    """Whether step 2 writes its swap rows with the Pallas row-scatter
+    kernel: on TPU, for 4-byte storage ((8, 128)-tiled, as the kernel
+    assumes) and tile-aligned blocks. bf16 is tiled (16, 128) and keeps
+    XLA's scatter, as do the other backends."""
+    return (platform == "tpu" and jnp.dtype(dtype).itemsize == 4
+            and geom.v % 128 == 0)
+
+
 @functools.lru_cache(maxsize=32)
 def _build(geom: LUGeometry, mesh_key, precision, backend: str,
            panel_chunk: int, donate: bool = False, resumable: bool = False,
            lookahead: bool = False, election: str = "gather",
            segs: tuple = (16, 16), tree: str = "pairwise",
-           update: str = "segments"):
+           update: str = "segments", swap_kernel: bool = False):
     """resumable=True builds the checkpoint/restart form: factor supersteps
     [k0, k1) given as TRACED scalars — one compile serves every segment of
     a checkpointed run — with the row-origin state as an explicit
     input/output (`lu_factor_steps`). lookahead=True builds the
     software-pipelined loop (panel + election carried one step ahead; see
-    body_la)."""
+    body_la). swap_kernel=True writes the step-2 swap rows with
+    `pallas_kernels.row_scatter` (`_swap_kernel` decides; tests force it
+    on a CPU mesh, where the kernel runs in the interpreter)."""
     mesh = lookup_mesh(mesh_key)
+    # the mesh's platform, not the default backend: a program compiled for
+    # a described TPU from a CPU host lowers the real kernel
+    interpret = next(iter(mesh.devices.flat)).platform != "tpu"
+    if swap_kernel:  # Pallas takes ~1 s to import: only where it runs
+        from conflux_tpu.ops import pallas_kernels
     v = geom.v
     Px, Py, Pz = geom.grid.Px, geom.grid.Py, geom.grid.Pz
     Ml, Nl = geom.Ml, geom.Nl
@@ -258,15 +274,13 @@ def _build(geom: LUGeometry, mesh_key, precision, backend: str,
             # k*v..(k+1)*v); the non-winner occupants move to the slots
             # vacated by external winners (i-th displaced occupant -> i-th
             # vacated position, both ascending — a canonical matching).
-            # The writes are a (Ml,)-indexed row scatter. XLA lowers it to
-            # a serial per-row loop (~10 ms/step at v=1024) — a round-2
-            # attempt to fold the writes into the step-6 segments as
-            # gather+selects was REVERTED: on a real v5e it was ~30%
-            # slower and silently produced garbage factors at N=32768
-            # (residual 29 vs 2.9e-05; correct on CPU at every tested
-            # size and on TPU at N<=16384, valid perm, bounded factor
-            # magnitudes — an XLA TPU miscompile at 4 GiB operands is the
-            # best available explanation; see docs/DESIGN.md §14).
+            # The writes are a (Ml,)-indexed row scatter: on TPU the
+            # Pallas row-scatter kernel (`swap_kernel`), elsewhere XLA's
+            # scatter, which on TPU is a serial per-row loop (~10 ms/step
+            # at N=32768, v=1024). A round-2 attempt to fold the writes
+            # into the step-6 segments as gather+selects was reverted: on
+            # a v5e it was ~30% slower and gave wrong factors at N=32768
+            # (docs/DESIGN.md §14).
             with jax.named_scope("step2_pivotrows"):
                 slots = k * v + jnp.arange(v, dtype=jnp.int32)
                 occ_is_winner = (wpos[None, :] == slots[:, None]).any(1)
@@ -314,16 +328,16 @@ def _build(geom: LUGeometry, mesh_key, precision, backend: str,
                 didx = loc_of(dest_disp)
                 disp_vals = jnp.where(z0, Drows.astype(dtype),
                                       jnp.zeros((), dtype))
-                # XLA's per-row scatter loop (~10 ms/step at v=1024,
-                # N=32768). Its ops carry no op_name metadata;
-                # `profiler.scope_map` credits them to this scope through
-                # the loop instruction that calls them. A pipelined
-                # Pallas row-DMA alternative existed rounds 3-4 behind
-                # swap='dma' but was deleted unadopted per the
-                # pre-decided criterion (docs/ROUND3.md #3: hardware A/B
-                # or deletion — the chip never recovered to run it); git
-                # history has the kernel.
-                Aloc = Aloc.at[didx].set(disp_vals, mode="drop")
+                # the kernel's precondition holds: dest_disp ascends in
+                # slot order (ext_sorted is sorted, disp_rank counts up),
+                # loc_of is monotone on the rows this x-rank owns, and
+                # every other entry (a winner's slot, a row owned
+                # elsewhere) maps to Ml and is dropped
+                if swap_kernel:
+                    Aloc = pallas_kernels.row_scatter(
+                        Aloc, didx, disp_vals, interpret=interpret)
+                else:
+                    Aloc = Aloc.at[didx].set(disp_vals, mode="drop")
                 orig = jnp.where(
                     own_d, lax.dynamic_update_slice(orig, worig, (li,)), orig)
                 orig = orig.at[didx].set(dorig, mode="drop")
@@ -623,11 +637,12 @@ def build_program(geom: LUGeometry, mesh, precision=None,
     """
     precision = blas.matmul_precision() if precision is None else precision
     backend = blas.get_backend() if backend is None else backend
-    cdtype = blas.compute_dtype(jnp.dtype(dtype)) if dtype is not None \
-        else jnp.float32
+    dtype = jnp.float32 if dtype is None else jnp.dtype(dtype)
+    cdtype = blas.compute_dtype(dtype)
     if panel_chunk is None:
         panel_chunk = blas.single_call_rows(geom.v, cdtype)
-    if donate and next(iter(mesh.devices.flat)).platform == "cpu":
+    platform = next(iter(mesh.devices.flat)).platform
+    if donate and platform == "cpu":
         donate = False  # CPU PJRT has no buffer donation (warns per call)
     if election not in ("gather", "butterfly"):
         raise ValueError(f"unknown election {election!r} (gather|butterfly)")
@@ -672,7 +687,8 @@ def build_program(geom: LUGeometry, mesh, precision=None,
         raise ValueError(f"unknown update {update!r} (segments|block)")
     return _build(geom, mesh_cache_key(mesh), precision, backend,
                   panel_chunk, donate, resumable, lookahead, election,
-                  tuple(segs), tree, update)
+                  tuple(segs), tree, update,
+                  _swap_kernel(platform, dtype, geom))
 
 
 def lu_factor_distributed(shards, geom: LUGeometry, mesh,
@@ -714,11 +730,9 @@ def lu_factor_distributed(shards, geom: LUGeometry, mesh,
     calls; see `ops.blas.tournament_winners`). Both are valid CALU
     elections; pivot choices can differ on ties, so results are
     comparable by residual, not bitwise.
-    (An experimental `swap='dma'` Pallas row-DMA alternative to the XLA
-    displacement scatter existed rounds 3-4; it was deleted unadopted
-    per the pre-decided hardware-A/B-or-delete criterion when the chip
-    stayed unreachable. Git history has the kernel and
-    its staged probe protocol.)
+    On TPU with 4-byte storage the step-2 swap rows are written by the
+    Pallas row-scatter kernel (`pallas_kernels.row_scatter`); elsewhere by
+    XLA's scatter. The two give the same bits.
     """
     from conflux_tpu.geometry import check_shards
 

@@ -3,12 +3,14 @@
 The trailing-matrix GEMM is where ~2/3 N^3 of the factorization's flops live
 (reference `conflux_opt.hpp:1626-1634`); this module provides an MXU-tiled
 Pallas implementation behind the `conflux_tpu.ops.blas` backend registry.
+`row_scatter` writes the LU's step-2 row swaps in place on TPU.
 Off-TPU (CPU simulation in tests) the kernels run in interpret mode.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -177,20 +179,205 @@ def _lu_block(a, alive, interpret: bool):
 
 
 # --------------------------------------------------------------------------- #
-# Row scatter (REMOVED, round 4)
+# Row scatter: a[idx[i]] = rows[i], in place, by aligned row groups
 # --------------------------------------------------------------------------- #
 #
-# An experimental pipelined row-DMA scatter (HBM -> VMEM -> HBM with
-# scalar-prefetched destination indices, in-place aliasing) lived here in
-# rounds 3-4 as the `swap='dma'` alternative to XLA's serial per-row
-# scatter loop (~10 ms/step at v=1024, N=32768). The pre-decided adoption
-# criterion (docs/ROUND3.md #3) required a staged hardware A/B with a
-# full-scale residual gate; the remote chip of that time never came back
-# to run it, so the kernel was deleted unadopted per VERDICT r3 item 3
-# ("no third state"). Git history (rounds 3-4) has the kernel,
-# its bring-up protocol (scripts/swap_probe.py), and the lesson that
-# direct HBM->HBM local DMA wedges the chip (local copies want a VMEM
-# side).
+# XLA lowers a row scatter into an (M, W) matrix to a serial loop, one row
+# per trip with a select and a bounds check around each write (~10 us a
+# row at W=32768 on a v5e). A DMA cannot write a single row either: the
+# HBM layout is tiled (8, 128) for 4-byte types, and Mosaic refuses a
+# 1-row slice of a tiled memref. So each destination's aligned 8-row group
+# is read into VMEM, the row is stored into it there (a dynamic 1-row load
+# and store are fine, a 1-row memref slice is not), and the group is
+# written back. Kept indices ascend (the caller's precondition), so the
+# destinations that share a group come one after another and ride one
+# read-modify-write, and every group in flight is distinct: several reads
+# can run ahead of the writes without a hazard. The source rows stream
+# through VMEM in aligned 8-row blocks, double-buffered. The matrix is
+# worked in column stripes, one grid step each, so the VMEM the kernel
+# takes is bounded whatever W is; a (32768, 32768) matrix is one stripe.
+# On a v5e the kernel is bound by HBM: at N=32768 it moves each displaced
+# row's 1 MiB group in and out at ~570 GB/s, with 4 to 10 slots alike.
+# Copies that name a column range are slower than whole-row ones (4.43 to
+# 4.58 ms, with 2, 3 or 6 slots, against 3.83 for ~985 rows into a
+# (32768, 32768) matrix), so a matrix of one stripe is copied by whole rows.
+
+_ROW_GROUP = 8       # sublanes of one (8, 128) tile of a 4-byte type
+_SCATTER_SLOTS = 6   # VMEM group buffers: reads run up to 5 groups ahead
+_SCATTER_LANES = 2048  # columns per row copy (16 vregs of f32)
+_SCATTER_VMEM = 8 << 20  # bytes of group and source buffers, under the
+                         # 16 MiB of scoped VMEM a v5e gives a kernel
+
+
+def _stripe_cols(w: int, itemsize: int) -> int:
+    """Columns of one stripe: the widest multiple of 128 that divides W and
+    keeps the (slots + 2) 8-row buffers within `_SCATTER_VMEM`."""
+    cap = _SCATTER_VMEM // ((_SCATTER_SLOTS + 2) * _ROW_GROUP * itemsize)
+    k = w // 128
+    return 128 * max(d for d in range(1, k + 1)
+                     if k % d == 0 and 128 * d <= cap)
+
+
+def _row_scatter_kernel(idx_ref, a_hbm, rows_hbm, o_hbm, grp, src, starts,
+                        gsem, ssem):
+    del a_hbm  # aliased with o_hbm: every read and write goes through o_hbm
+    m = o_hbm.shape[0]
+    n = rows_hbm.shape[0]
+    g8 = _ROW_GROUP
+    slots, _, w = grp.shape  # w: the stripe's columns
+    if o_hbm.shape[1] != w:
+        stripe = pl.ds(pl.multiple_of(pl.program_id(0) * w, 128), w)
+        o_hbm, rows_hbm = o_hbm.at[:, stripe], rows_hbm.at[:, stripe]
+    nblk = n // g8
+    lanes = math.gcd(w, _SCATTER_LANES)
+
+    # runs: maximal stretches of i whose kept destinations share a group;
+    # starts[r] is the first kept i of run r, starts[nrun] == n
+    def scan(i, carry):
+        nrun, gprev = carry
+        d = idx_ref[i]
+        new = (d < m) & (d // g8 != gprev)
+
+        @pl.when(new)
+        def _():
+            starts[nrun] = i
+
+        return nrun + new.astype(jnp.int32), jnp.where(d < m, d // g8, gprev)
+
+    nrun, _ = jax.lax.fori_loop(0, n, scan, (jnp.int32(0), jnp.int32(-1)))
+    starts[nrun] = n
+
+    def group(r):
+        return pl.ds(pl.multiple_of(idx_ref[starts[r]] // g8 * g8, g8), g8)
+
+    def read(r, slot):
+        return pltpu.make_async_copy(o_hbm.at[group(r)], grp.at[slot],
+                                     gsem.at[0, slot])
+
+    def write(r, slot):
+        return pltpu.make_async_copy(grp.at[slot], o_hbm.at[group(r)],
+                                     gsem.at[1, slot])
+
+    def fetch(j):
+        return pltpu.make_async_copy(
+            rows_hbm.at[pl.ds(pl.multiple_of(j * g8, g8), g8)],
+            src.at[j % 2], ssem.at[j % 2])
+
+    def advance(jcur, j):
+        """Wait for source blocks up to j, prefetching the one after."""
+        def step(jc):
+            jc = jc + 1
+            fetch(jc).wait()
+
+            @pl.when(jc + 1 < nblk)
+            def _():
+                fetch(jc + 1).start()
+
+            return jc
+
+        return jax.lax.while_loop(lambda jc: jc < j, step, jcur)
+
+    def put(i, slot, jcur):
+        d = idx_ref[i]
+        jcur = advance(jcur, i // g8)
+
+        @pl.when(d < m)
+        def _():
+            for c in range(0, w, lanes):
+                cols = pl.ds(c, lanes)
+                grp[slot, pl.ds(d % g8, 1), cols] = src[
+                    (i // g8) % 2, pl.ds(i % g8, 1), cols]
+
+        return jcur
+
+    def run(r, jcur):
+        slot = r % slots
+        read(r, slot).wait()
+        jcur = jax.lax.fori_loop(starts[r], starts[r + 1],
+                                 lambda i, jc: put(i, slot, jc), jcur)
+        write(r, slot).start()
+        # the slot written one run ago takes the read `slots - 1` runs ahead
+        prev = (r + slots - 1) % slots
+
+        @pl.when(r >= 1)
+        def _():
+            write(r - 1, prev).wait()
+
+        @pl.when(r + slots - 1 < nrun)
+        def _():
+            read(r + slots - 1, prev).start()
+
+        return jcur
+
+    fetch(0).start()
+    for r in range(slots - 1):
+        @pl.when(r < nrun)
+        def _(r=r):
+            read(r, r).start()
+
+    jcur = jax.lax.fori_loop(0, nrun, run, jnp.int32(-1))
+
+    @pl.when(nrun >= 1)
+    def _():
+        write(nrun - 1, (nrun - 1) % slots).wait()
+
+    advance(jcur, nblk - 1)  # drain the source stream
+
+
+@functools.partial(jax.jit, static_argnames=("cols", "interpret"))
+def _row_scatter(a, idx, rows, cols, interpret=False):
+    m, w = a.shape
+    n = rows.shape[0]
+    slots = _SCATTER_SLOTS
+    return pl.pallas_call(
+        _row_scatter_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(w // cols,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[
+                pltpu.VMEM((slots, _ROW_GROUP, cols), a.dtype),
+                pltpu.VMEM((2, _ROW_GROUP, cols), a.dtype),
+                pltpu.SMEM((n + 1,), jnp.int32),
+                pltpu.SemaphoreType.DMA((2, slots)),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, w), a.dtype,
+                                       vma=jax.typeof(a).vma),
+        input_output_aliases={1: 0},
+        cost_estimate=pl.CostEstimate(
+            flops=0, transcendentals=0,
+            bytes_accessed=(2 * _ROW_GROUP + 1) * n * w * a.dtype.itemsize),
+        interpret=pltpu.InterpretParams() if interpret else False,
+    )(idx, a, rows)
+
+
+def row_scatter(a: jax.Array, idx: jax.Array, rows: jax.Array, *,
+                interpret: bool = False) -> jax.Array:
+    """`a.at[idx].set(rows, mode="drop")`, written in place by a Pallas TPU
+    kernel: a (M, W), idx (n,) int32, rows (n, W).
+
+    Precondition: the kept indices (those < M) ascend strictly; indices
+    >= M are dropped and may sit anywhere; none is negative. A 4-byte dtype
+    (the (8, 128) tiling), M and n multiples of 8, W a multiple of 128.
+    Pass `a` where the caller can give it up (a donated or loop-carried
+    buffer): the output aliases it. `interpret` runs the TPU interpreter,
+    which simulates every DMA (tests only: slow at real sizes).
+    """
+    m, w = a.shape
+    n = rows.shape[0]
+    if (a.dtype.itemsize != 4 or m % _ROW_GROUP or n % _ROW_GROUP
+            or w % 128 or rows.shape[1] != w or idx.shape != (n,)):
+        raise ValueError(
+            f"row_scatter needs a 4-byte (M, W) matrix with M, n multiples "
+            f"of {_ROW_GROUP} and W of 128, idx (n,), rows (n, W); got a "
+            f"{a.dtype} {a.shape}, idx {idx.shape}, rows {rows.shape}")
+    return _row_scatter(a, idx.astype(jnp.int32), rows.astype(a.dtype),
+                        _stripe_cols(w, a.dtype.itemsize),
+                        interpret=interpret)
 
 
 def lu_block(a: jax.Array, alive: jax.Array):

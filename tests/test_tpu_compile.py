@@ -103,7 +103,30 @@ def test_panel_kernel_compiles_at_row_ceiling(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("m,w", [(32768, 32768), (37888, 75776)],
+                         ids=["1x1x1", "2x1x2"])
+def test_row_scatter_kernel_compiles_at_full_size(one_chip, m, w):
+    """The step-2 row scatter at full size, 1024 rows, written in place (no
+    temporary, the matrix aliased to the output): the one-chip cell's
+    (32768, 32768) f32 matrix, and a local block of the N=75776 LU on the
+    2x1x2 grid, whose width the kernel covers in column stripes to stay
+    within VMEM."""
+    from conflux_tpu.ops import pallas_kernels
+
+    n = 1024
+    compiled = jax.jit(pallas_kernels.row_scatter, donate_argnums=0).lower(
+        _spec((m, w), one_chip), _spec((n,), one_chip, jnp.int32),
+        _spec((n, w), one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == m * w * 4
+    assert mem.temp_size_in_bytes == 0
+
+
 def test_lu_program_compiles_and_fits(topo):
+    """The LU program for one v5e: donated, within HBM, and its step-2
+    swap rows written by the row-scatter kernel, with no scatter loop over
+    the matrix and no temporary copy of it."""
     from conflux_tpu.geometry import Grid3, LUGeometry
     from conflux_tpu.lu.distributed import build_program
     from conflux_tpu.parallel.mesh import AXIS_X, AXIS_Y, make_mesh
@@ -114,6 +137,19 @@ def test_lu_program_compiles_and_fits(topo):
     fn = build_program(geom, mesh, donate=True, dtype=jnp.float32)
     shards = _spec((1, 1, geom.Ml, geom.Nl),
                    NamedSharding(mesh, P(AXIS_X, AXIS_Y, None, None)))
-    mem = fn.lower(shards).compile().memory_analysis()
+    compiled = fn.lower(shards).compile()
+    mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == mem.argument_size_in_bytes  # donated
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    assert mem.temp_size_in_bytes < geom.Ml * geom.Nl * 4
+
+    matrix = f"f32[{geom.Ml},{geom.Nl}]"
+    lines = compiled.as_text().splitlines()
+    kernel = [ln for ln in lines if "tpu_custom_call" in ln
+              and "step2_pivotrows" in ln]
+    assert kernel and all(f"= {matrix}" in ln for ln in kernel), kernel
+    # XLA's lowering of the matrix scatter: a while loop that carries the
+    # matrix, or a scatter into it, under the step-2 scope
+    loops = [ln for ln in lines if "step2_pivotrows" in ln and matrix in ln
+             and (" while(" in ln or " scatter(" in ln)]
+    assert not loops, loops
