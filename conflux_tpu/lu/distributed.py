@@ -38,7 +38,11 @@ shapes throughout:
 Per superstep: 5 collectives (panel psum over (y,z), nominee all_gather
 over x, pivot-row psum over (x,z), displaced-row psum over (x,z), small
 bookkeeping psums), two chunked tournament factorizations, two TRSMs over
-live segments, and the segmented trailing GEMMs.
+live segments, and the segmented trailing GEMMs. Every collective sits in
+a `comm_*` named scope nested in its step's scope (`comm_panel`,
+`comm_election`, `comm_winner_rows`, `comm_displaced_rows`,
+`comm_diag_panel`; `comm_layers` and `comm_perm` in the epilogue), so a
+trace separates communication from compute inside each step.
 
 Factors come back in *pivoted row order* together with `perm` (M,), the
 original row index at each global position: A[perm] == L @ U.
@@ -163,10 +167,10 @@ def _build(geom: LUGeometry, mesh_key, precision, backend: str,
             lj = jnp.asarray((k // Py) * v, jnp.int32)  # k may be a py int
             panel_loc = lax.dynamic_slice(
                 Aloc, (jnp.zeros((), jnp.int32), lj), (Ml, v))
-            return lax.psum(
-                jnp.where(y == j_owner, panel_loc, jnp.zeros((), dtype)),
-                (AXIS_Y, AXIS_Z),
-            ).astype(cdtype)
+            mine = jnp.where(y == j_owner, panel_loc, jnp.zeros((), dtype))
+            with jax.named_scope("comm_panel"):
+                panel = lax.psum(mine, (AXIS_Y, AXIS_Z))
+            return panel.astype(cdtype)
 
         def elect(panel, k):
             """Tournament pivoting over x (ref step 1): candidates are
@@ -245,8 +249,9 @@ def _build(geom: LUGeometry, mesh_key, precision, backend: str,
                     (nom, nid, jnp.zeros((v, v), cdtype)), Px, AXIS_X,
                     reduce_pair)
                 return lu00, nid
-            blks = lax.all_gather(nom, AXIS_X)  # (Px, v, v)
-            poss = lax.all_gather(nid, AXIS_X)  # (Px, v)
+            with jax.named_scope("comm_election"):
+                blks = lax.all_gather(nom, AXIS_X)  # (Px, v, v)
+                poss = lax.all_gather(nid, AXIS_X)  # (Px, v)
             flat = blks.reshape(Px * v, v)
             # the election tournament is batched (no liveness
             # structure), so its chunk stays within the batched
@@ -300,26 +305,27 @@ def _build(geom: LUGeometry, mesh_key, precision, backend: str,
 
                 # winners' full rows + ids, reduced over (x, z) (ref step 3)
                 wloc = loc_of(wpos)
-                Prows = lax.psum(
-                    jnp.take(Aloc, wloc, axis=0, mode="fill", fill_value=0),
-                    (AXIS_X, AXIS_Z))  # (v, Nl)
-                worig = lax.psum(
-                    jnp.take(orig, wloc, mode="fill", fill_value=0), AXIS_X)
+                w_rows = jnp.take(Aloc, wloc, axis=0, mode="fill",
+                                  fill_value=0)
+                w_orig = jnp.take(orig, wloc, mode="fill", fill_value=0)
+                with jax.named_scope("comm_winner_rows"):
+                    Prows = lax.psum(w_rows, (AXIS_X, AXIS_Z))  # (v, Nl)
+                    worig = lax.psum(w_orig, AXIS_X)
                 # displaced occupants' full rows + ids + panel rows
                 own_d = x == i_owner
-                Drows = lax.psum(
-                    jnp.where(own_d,
-                              lax.dynamic_slice(Aloc, (li, i0), (v, Nl)),
-                              jnp.zeros((), dtype)),
-                    (AXIS_X, AXIS_Z))  # (v, Nl)
-                dorig = lax.psum(
-                    jnp.where(own_d, lax.dynamic_slice(orig, (li,), (v,)), 0),
-                    AXIS_X)
-                diag_panel = lax.psum(
-                    jnp.where(own_d,
-                              lax.dynamic_slice(panel, (li, i0), (v, v)),
-                              jnp.zeros((), cdtype)),
-                    AXIS_X)  # (v, v)
+                d_rows = jnp.where(own_d,
+                                   lax.dynamic_slice(Aloc, (li, i0), (v, Nl)),
+                                   jnp.zeros((), dtype))
+                d_orig = jnp.where(own_d,
+                                   lax.dynamic_slice(orig, (li,), (v,)), 0)
+                d_panel = jnp.where(own_d,
+                                    lax.dynamic_slice(panel, (li, i0), (v, v)),
+                                    jnp.zeros((), cdtype))
+                with jax.named_scope("comm_displaced_rows"):
+                    Drows = lax.psum(d_rows, (AXIS_X, AXIS_Z))  # (v, Nl)
+                    dorig = lax.psum(d_orig, AXIS_X)
+                with jax.named_scope("comm_diag_panel"):
+                    diag_panel = lax.psum(d_panel, AXIS_X)  # (v, v)
 
                 # swap writes: vacated positions get the displaced rows now
                 # (they stay active and take the trailing update); diagonal
@@ -562,9 +568,10 @@ def _build(geom: LUGeometry, mesh_key, precision, backend: str,
                                             jnp.zeros((), dtype)),
                             (art["li"], i0)),
                         slab)
-                    panel_next = lax.psum(
-                        jnp.where(y == j1, slab, jnp.zeros((), dtype)),
-                        (AXIS_Y, AXIS_Z)).astype(cdtype)
+                    mine = jnp.where(y == j1, slab, jnp.zeros((), dtype))
+                    with jax.named_scope("comm_panel"):
+                        panel_next = lax.psum(mine, (AXIS_Y, AXIS_Z))
+                    panel_next = panel_next.astype(cdtype)
                 with jax.named_scope("step1_pivoting"):
                     lu00n, wposn = elect(panel_next, kn)
                 return panel_next, lu00n, wposn
@@ -587,19 +594,22 @@ def _build(geom: LUGeometry, mesh_key, precision, backend: str,
         with jax.named_scope("step8_perm"):
             # all factors live on layer 0; psum makes the output
             # z-replicated
-            Aout = lax.psum(Aloc, AXIS_Z)
+            with jax.named_scope("comm_layers"):
+                Aout = lax.psum(Aloc, AXIS_Z)
             # assemble the permutation: original row id at every global
             # position
             perm = jnp.zeros((Mcap,), jnp.int32).at[gp].set(orig)
-            perm = lax.psum(perm, AXIS_X)
-            # identical on every device already; pmax re-establishes
-            # replication for the out_spec
-            perm = lax.pmax(perm, (AXIS_Y, AXIS_Z))
-            if orig_blk is None:
-                return Aout[None, None], perm
-            # resumable form: the row-origin state rides along (replicated
-            # over y/z by pmax — every y/z holds the same x-row's state)
-            orig_out = lax.pmax(orig, (AXIS_Y, AXIS_Z))
+            with jax.named_scope("comm_perm"):
+                perm = lax.psum(perm, AXIS_X)
+                # identical on every device already; pmax re-establishes
+                # replication for the out_spec
+                perm = lax.pmax(perm, (AXIS_Y, AXIS_Z))
+                if orig_blk is None:
+                    return Aout[None, None], perm
+                # resumable form: the row-origin state rides along
+                # (replicated over y/z by pmax — every y/z holds the same
+                # x-row's state)
+                orig_out = lax.pmax(orig, (AXIS_Y, AXIS_Z))
             return Aout[None, None], orig_out[None], perm
 
     shard_spec = P(AXIS_X, AXIS_Y, None, None)

@@ -176,6 +176,9 @@ def butterfly_allreduce(vals: tuple, Px: int, axis: str, reduce_pair):
     and are discarded by coordinate selects, so reducers must tolerate
     (not crash on) all-zero inputs — true of the CALU/TSQR reducers,
     whose zero-stack factorizations are well-defined garbage.
+
+    The exchanges run in a `comm_butterfly` named scope, so a trace tells
+    them from the reducer's compute.
     """
     import jax.numpy as jnp
     from jax import lax
@@ -187,14 +190,16 @@ def butterfly_allreduce(vals: tuple, Px: int, axis: str, reduce_pair):
         # fold: overflow rank p+i's contribution joins rank i's, ordered
         # by the lower coordinate (rank i's own vals first)
         perm = [(p + i, i) for i in range(r)]
-        recv = tuple(lax.ppermute(v, axis, perm) for v in vals)
+        with jax.named_scope("comm_butterfly"):
+            recv = tuple(lax.ppermute(v, axis, perm) for v in vals)
         folded = tuple(reduce_pair(vals, recv))
         vals = tuple(jnp.where(x < r, f, v)
                      for f, v in zip(folded, vals))
     for rnd in range(p.bit_length() - 1):
         bit = 1 << rnd
         perm = [(i, i ^ bit) for i in range(p)]
-        others = tuple(lax.ppermute(v, axis, perm) for v in vals)
+        with jax.named_scope("comm_butterfly"):
+            others = tuple(lax.ppermute(v, axis, perm) for v in vals)
         low_first = (x & bit) == 0
         top = tuple(jnp.where(low_first, a, b)
                     for a, b in zip(vals, others))
@@ -205,7 +210,8 @@ def butterfly_allreduce(vals: tuple, Px: int, axis: str, reduce_pair):
         # unfold: the subcube result is replicated over [0, p); hand the
         # overflow ranks their copy
         perm = [(i, p + i) for i in range(r)]
-        recv = tuple(lax.ppermute(v, axis, perm) for v in vals)
+        with jax.named_scope("comm_butterfly"):
+            recv = tuple(lax.ppermute(v, axis, perm) for v in vals)
         vals = tuple(jnp.where(x >= p, o, v)
                      for o, v in zip(recv, vals))
     return vals

@@ -647,6 +647,9 @@ class CounterWindow:
 # (solve_perm/solve_fwd/solve_bwd) and the QR loop (qr_*)
 PHASE_RE = (r"step\d+_[a-z0-9]+|(?:reduce|cholesky|update|compute|scatter)"
             r"A\d\d|solve_[a-z]+|qr_[a-z_]+")
+# the scope a mesh program gives each of its collectives, nested inside
+# the phase it serves (comm_panel, comm_election, comm_rows, ...)
+COMM_RE = r"comm_[a-z0-9_]+"
 UNATTRIBUTED = "(unattributed)"
 
 _INSTR_RE = re.compile(r"\s+(?:ROOT )?%([\w.-]+) = ")
@@ -875,15 +878,23 @@ def phase_table(trace_dir: str, hlo_text: str,
     programs that share a module name, as every shard_map program did
     before they were named, are told apart. Each op is credited its self
     time and its phase by :func:`scope_map`; ops it leaves out are
-    '(unattributed)'. Prints the table, then the traced window's device
-    busy share and its 5 longest idle gaps, each named by the innermost
-    host span of a :func:`region` this process has timed that covers it.
-    Raises ValueError for a trace with no device ops (a CPU run).
+    '(unattributed)'. An op whose own `op_name` names a collective's scope
+    (`COMM_RE`, e.g. `comm_panel`) is also counted in a row
+    '<phase>/<scope>', printed under its phase: such rows break a phase
+    down and are part of its row, not added to it. Prints the table, then
+    the traced window's device busy share and its 5 longest idle gaps,
+    each named by the innermost host span of a :func:`region` this
+    process has timed that covers it. Raises ValueError for a trace with
+    no device ops (a CPU run).
     """
     import bisect
 
     scope = scope_map(hlo_text, phase_re)
-    heads = {it.head for it in _instructions(hlo_text).values()}
+    ins = _instructions(hlo_text)
+    heads = {it.head for it in ins.values()}
+    comm_re = re.compile(COMM_RE)
+    comm = {tok: c for tok, it in ins.items()
+            if it.op_name and (c := _phase_of(it.op_name, comm_re))}
     m = re.search(r"^HloModule ([\w.-]+)", hlo_text, re.M)
     module = m.group(1) if m else ""
     pd = _load_trace(trace_dir)
@@ -910,15 +921,27 @@ def phase_table(trace_dir: str, hlo_text: str,
             runs += 1
             for i in range(lo, hi):
                 if ops[i][2] <= t1:
-                    row = agg[scope.get(_trace_head(ops[i][0])[0],
-                                        UNATTRIBUTED)]
-                    row[0] += own[i] / 1e6
-                    row[1] += 1
-    total = sum(t for t, _ in agg.values()) or 1.0
+                    tok = _trace_head(ops[i][0])[0]
+                    ph = scope.get(tok, UNATTRIBUTED)
+                    rows = [ph] + ([f"{ph}/{comm[tok]}"] if tok in comm
+                                   else [])
+                    for key in rows:
+                        agg[key][0] += own[i] / 1e6
+                        agg[key][1] += 1
+    phases = {k: r for k, r in agg.items() if "/" not in k}
+    total = sum(t for t, _ in phases.values()) or 1.0
     lines = [f"{module}: {runs} execution(s) on {len(chips)} chip(s)",
              f"{'PHASE':<24}{'OPS':>8}{'DEVICE ms':>14}{'%':>8}"]
-    for ph, (t, n) in sorted(agg.items(), key=lambda kv: -kv[1][0]):
+
+    def by_time(rows):
+        return sorted(rows, key=lambda kv: -kv[1][0])
+
+    for ph, (t, n) in by_time(phases.items()):
         lines.append(f"{ph:<24}{n:>8}{t:>14.3f}{100 * t / total:>8.1f}")
+        for key, (tc, nc) in by_time((k, r) for k, r in agg.items()
+                                     if k.startswith(ph + "/")):
+            lines.append(f"  {key[len(ph) + 1:]:<22}{nc:>8}{tc:>14.3f}"
+                         f"{100 * tc / total:>8.1f}")
     lines += _window_lines(pd, _line_events(chips[0], "XLA Ops"))
     print("\n".join(lines))
     return {ph: (t, n) for ph, (t, n) in agg.items()}

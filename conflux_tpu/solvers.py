@@ -85,7 +85,8 @@ def _check_solve_rhs(geom, b) -> None:
 def _diag_tile_rows(Aloc, k, x_, gcol, v, Px, Nl, dtype):
     """Shared by the LU and Cholesky mesh solves: step k's diagonal row
     tile — (v, Nl) local columns via a masked psum over 'x', and the
-    (v, v) diagonal block via an index scatter + psum over 'y'."""
+    (v, v) diagonal block via an index scatter + psum over 'y' (named
+    scopes `comm_rows`, `comm_diag`)."""
     from conflux_tpu.parallel.mesh import AXIS_X, AXIS_Y
 
     li = ((k // Px) * v).astype(jnp.int32)
@@ -93,11 +94,13 @@ def _diag_tile_rows(Aloc, k, x_, gcol, v, Px, Nl, dtype):
         x_ == k % Px,
         lax.dynamic_slice(Aloc, (li, jnp.zeros((), jnp.int32)), (v, Nl)),
         jnp.zeros((), dtype))
-    rows = lax.psum(part, AXIS_X)  # (v, Nl): my cols of those rows
+    with jax.named_scope("comm_rows"):
+        rows = lax.psum(part, AXIS_X)  # (v, Nl): my cols of those rows
     idx = jnp.where((gcol >= k * v) & (gcol < (k + 1) * v), gcol - k * v, v)
     diag = jnp.zeros((v, v), dtype).at[:, idx].add(
         jnp.where(idx[None, :] < v, rows, 0.0), mode="drop")
-    diag = lax.psum(diag, AXIS_Y)
+    with jax.named_scope("comm_diag"):
+        diag = lax.psum(diag, AXIS_Y)
     return rows, diag
 
 
@@ -111,6 +114,8 @@ def lu_solve_distributed(shards, perm, geom, mesh, b) -> jax.Array:
     entries, and a psum over 'y' completes the inner products. O(N^2/P)
     flops over 2*n_steps latency-bound steps — triangular solves are
     sequential by nature; the reference has no distributed solve at all.
+    Each collective sits in a `comm_*` named scope inside `solve_fwd` or
+    `solve_bwd`.
 
     b may be (N,) or (N, nrhs) — multi-RHS runs all columns through each
     substitution step at once (LAPACK getrs semantics). Returns x of b's
@@ -155,7 +160,8 @@ def _build_lu_solve(geom, mesh_key):
             solved = gcol < k * v
             s = jnp.matmul(rows, jnp.where(solved[:, None], yv[gcol], 0.0),
                            precision=lax.Precision.HIGHEST)
-            s = lax.psum(s, AXIS_Y)  # (v, nrhs)
+            with jax.named_scope("comm_dots"):
+                s = lax.psum(s, AXIS_Y)  # (v, nrhs)
             kv = jnp.asarray(k * v, jnp.int32)
             bk = lax.dynamic_slice(bp, (kv, i0), (v, nrhs))
             yk = blas.trsm_left_lower_unit(blas.unit_lower(diag), bk - s)
@@ -170,7 +176,8 @@ def _build_lu_solve(geom, mesh_key):
             ahead = gcol >= (k + 1) * v
             s = jnp.matmul(rows, jnp.where(ahead[:, None], xv[gcol], 0.0),
                            precision=lax.Precision.HIGHEST)
-            s = lax.psum(s, AXIS_Y)
+            with jax.named_scope("comm_dots"):
+                s = lax.psum(s, AXIS_Y)
             kv = jnp.asarray(k * v, jnp.int32)
             yk = lax.dynamic_slice(yv, (kv, i0), (v, nrhs))
             xk = blas.trsm_left_upper(jnp.triu(diag), yk - s)
@@ -181,7 +188,8 @@ def _build_lu_solve(geom, mesh_key):
             # replicated by construction (pure collectives); replicate (a
             # complex-safe pmax) satisfies the out_spec's replication check
             from conflux_tpu.parallel.mesh import replicate
-            return replicate(xv, (AXIS_X, AXIS_Y, AXIS_Z))
+            with jax.named_scope("comm_replicate"):
+                return replicate(xv, (AXIS_X, AXIS_Y, AXIS_Z))
 
     sharded = shard_map(
         device_fn,

@@ -153,3 +153,35 @@ def test_lu_program_compiles_and_fits(topo):
     loops = [ln for ln in lines if "step2_pivotrows" in ln and matrix in ln
              and (" while(" in ln or " scatter(" in ln)]
     assert not loops, loops
+
+
+def test_mesh_cell_programs_compile_and_fit(topo):
+    """The four-chip cell's programs besides the factor, at full size
+    (N=65536 on the 2x2x1 grid of a described v5e:2x2): the distributed
+    solve, the benchmark's shard generator and its exact sharded
+    residual each fit a chip beside the 4 GiB of factors."""
+    from benchmark import reference_mesh
+    from conflux_tpu.geometry import Grid3, LUGeometry
+    from conflux_tpu.parallel.mesh import (
+        AXIS_X, AXIS_Y, make_mesh, mesh_cache_key)
+    from conflux_tpu.solvers import _build_lu_solve
+
+    n, v, grid = 65536, 1024, Grid3(2, 2, 1)
+    geom = LUGeometry.create(n, n, v, grid)
+    mesh = make_mesh(grid, devices=topo.devices[:4])
+    shards = _spec((2, 2, geom.Ml, geom.Nl),
+                   NamedSharding(mesh, P(AXIS_X, AXIS_Y, None, None)))
+    rep = NamedSharding(mesh, P())
+    perm, b = _spec((n,), rep, jnp.int32), _spec((n,), rep)
+    seed = _spec((), rep, jnp.uint32)
+    factors = geom.Ml * geom.Nl * 4
+    for fn, args in (
+            (_build_lu_solve(geom, mesh_cache_key(mesh)), (shards, perm, b)),
+            (reference_mesh.shard_generator(n, v, mesh, (AXIS_X, AXIS_Y)),
+             (seed,)),
+            (reference_mesh._mesh_ssq(n, v, mesh, AXIS_X, AXIS_Y),
+             (shards, perm, seed))):
+        mem = fn.lower(*args).compile().memory_analysis()
+        # per chip: the factors, the program's own, and what it returns
+        assert (factors + mem.temp_size_in_bytes + mem.output_size_in_bytes
+                < 12e9), (fn, mem)
