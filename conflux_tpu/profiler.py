@@ -648,7 +648,7 @@ class CounterWindow:
 PHASE_RE = (r"step\d+_[a-z0-9]+|(?:reduce|cholesky|update|compute|scatter)"
             r"A\d\d|solve_[a-z]+|qr_[a-z_]+")
 # the scope a mesh program gives each of its collectives, nested inside
-# the phase it serves (comm_panel, comm_election, comm_rows, ...)
+# the phase it serves (comm_panel, comm_election, comm_dots, ...)
 COMM_RE = r"comm_[a-z0-9_]+"
 UNATTRIBUTED = "(unattributed)"
 

@@ -82,36 +82,53 @@ def _check_solve_rhs(geom, b) -> None:
             f"{n}; pad the system identity-extended before factoring")
 
 
-def _diag_tile_rows(Aloc, k, x_, gcol, v, Px, Nl, dtype):
-    """Shared by the LU and Cholesky mesh solves: step k's diagonal row
-    tile — (v, Nl) local columns via a masked psum over 'x', and the
-    (v, v) diagonal block via an index scatter + psum over 'y' (named
-    scopes `comm_rows`, `comm_diag`)."""
+def _solve_block(Aloc, k, vec, known, rhs, tri_solve, gcol, v, Px, Py):
+    """Block step k of a mesh triangular sweep, shared by the LU,
+    Cholesky-forward and QR mesh solves: returns
+    tri_solve(D, rhs - A[tile k, known] @ vec[known]) for row tile k's
+    diagonal block D, replicated on every device.
+
+    Each device multiplies its (v, Nl) slice of row tile k by its known
+    entries of `vec`; only the x-rank owning the tile keeps the product,
+    and one psum over ('x', 'y') of the (v, nrhs) partial products
+    (scope `comm_dots`) completes it. The device holding D reads it in
+    place and solves; every other device solves against the identity and
+    contributes zeros to the psum over ('x', 'y') that replicates the
+    (v, nrhs) block (scope `comm_block`). Each sum adds exact zeros, so
+    the result is the same on every grid.
+    """
     from conflux_tpu.parallel.mesh import AXIS_X, AXIS_Y
 
+    x_ = lax.axis_index(AXIS_X)
+    y_ = lax.axis_index(AXIS_Y)
     li = ((k // Px) * v).astype(jnp.int32)
-    part = jnp.where(
-        x_ == k % Px,
-        lax.dynamic_slice(Aloc, (li, jnp.zeros((), jnp.int32)), (v, Nl)),
-        jnp.zeros((), dtype))
-    with jax.named_scope("comm_rows"):
-        rows = lax.psum(part, AXIS_X)  # (v, Nl): my cols of those rows
-    idx = jnp.where((gcol >= k * v) & (gcol < (k + 1) * v), gcol - k * v, v)
-    diag = jnp.zeros((v, v), dtype).at[:, idx].add(
-        jnp.where(idx[None, :] < v, rows, 0.0), mode="drop")
-    with jax.named_scope("comm_diag"):
-        diag = lax.psum(diag, AXIS_Y)
-    return rows, diag
+    lj = ((k // Py) * v).astype(jnp.int32)
+    zero = jnp.zeros((), Aloc.dtype)
+    rows = lax.dynamic_slice(Aloc, (li, jnp.zeros((), jnp.int32)),
+                             (v, Aloc.shape[1]))
+    s = jnp.matmul(rows, jnp.where(known[:, None], vec[gcol], zero),
+                   precision=lax.Precision.HIGHEST)
+    row_owner = x_ == k % Px
+    with jax.named_scope("comm_dots"):
+        s = lax.psum(jnp.where(row_owner, s, zero), (AXIS_X, AXIS_Y))
+    owner = row_owner & (y_ == k % Py)
+    diag = jnp.where(owner, lax.dynamic_slice(Aloc, (li, lj), (v, v)),
+                     jnp.eye(v, dtype=Aloc.dtype))
+    blk = tri_solve(diag, rhs - s)
+    with jax.named_scope("comm_block"):
+        return lax.psum(jnp.where(owner, blk, zero), (AXIS_X, AXIS_Y))
 
 
 def lu_solve_distributed(shards, perm, geom, mesh, b) -> jax.Array:
     """Solve A x = b on the mesh, from `lu_factor_distributed`'s outputs.
 
     The factors are block-cyclic in *pivoted row order* (LAPACK layout), so
-    the solve is plain block forward/back substitution over tile steps: per
-    step, the diagonal tile's v rows are assembled with one masked psum
-    over 'x', each device dots them against its already-solved column
-    entries, and a psum over 'y' completes the inner products. O(N^2/P)
+    the solve is plain block forward/back substitution over tile steps
+    (`_solve_block`): per step, the devices owning the diagonal tile's v
+    rows dot their columns of them against the already-solved entries, one
+    psum over ('x', 'y') of those (v, nrhs) partial products completes the
+    inner products, the device holding the diagonal block solves it in
+    place, and a second (v, nrhs) psum replicates the solved block. O(N^2/P)
     flops over 2*n_steps latency-bound steps — triangular solves are
     sequential by nature; the reference has no distributed solve at all.
     Each collective sits in a `comm_*` named scope inside `solve_fwd` or
@@ -141,7 +158,6 @@ def _build_lu_solve(geom, mesh_key):
     Ml, Nl, n = geom.Ml, geom.Nl, geom.n_steps
 
     def device_fn(blk, perm, b):
-        x_ = lax.axis_index(AXIS_X)
         y_ = lax.axis_index(AXIS_Y)
         dtype = blas.compute_dtype(blk.dtype)
         with jax.named_scope("solve_load"):
@@ -156,15 +172,12 @@ def _build_lu_solve(geom, mesh_key):
         i0 = jnp.zeros((), jnp.int32)
 
         def fwd(k, yv):
-            rows, diag = _diag_tile_rows(Aloc, k, x_, gcol, v, Px, Nl, dtype)
-            solved = gcol < k * v
-            s = jnp.matmul(rows, jnp.where(solved[:, None], yv[gcol], 0.0),
-                           precision=lax.Precision.HIGHEST)
-            with jax.named_scope("comm_dots"):
-                s = lax.psum(s, AXIS_Y)  # (v, nrhs)
             kv = jnp.asarray(k * v, jnp.int32)
             bk = lax.dynamic_slice(bp, (kv, i0), (v, nrhs))
-            yk = blas.trsm_left_lower_unit(blas.unit_lower(diag), bk - s)
+            yk = _solve_block(
+                Aloc, k, yv, gcol < k * v, bk,
+                lambda d, r: blas.trsm_left_lower_unit(blas.unit_lower(d), r),
+                gcol, v, Px, Py)
             return lax.dynamic_update_slice(yv, yk, (kv, i0))
 
         with jax.named_scope("solve_fwd"):
@@ -172,15 +185,12 @@ def _build_lu_solve(geom, mesh_key):
 
         def bwd(i, xv):
             k = n - 1 - i
-            rows, diag = _diag_tile_rows(Aloc, k, x_, gcol, v, Px, Nl, dtype)
-            ahead = gcol >= (k + 1) * v
-            s = jnp.matmul(rows, jnp.where(ahead[:, None], xv[gcol], 0.0),
-                           precision=lax.Precision.HIGHEST)
-            with jax.named_scope("comm_dots"):
-                s = lax.psum(s, AXIS_Y)
             kv = jnp.asarray(k * v, jnp.int32)
             yk = lax.dynamic_slice(yv, (kv, i0), (v, nrhs))
-            xk = blas.trsm_left_upper(jnp.triu(diag), yk - s)
+            xk = _solve_block(
+                Aloc, k, xv, gcol >= (k + 1) * v, yk,
+                lambda d, r: blas.trsm_left_upper(jnp.triu(d), r),
+                gcol, v, Px, Py)
             return lax.dynamic_update_slice(xv, xk, (kv, i0))
 
         with jax.named_scope("solve_bwd"):
@@ -258,14 +268,12 @@ def _build_cholesky_solve(geom, mesh_key):
         i0 = jnp.zeros((), jnp.int32)
 
         def fwd(k, yv):
-            rows, diag = _diag_tile_rows(Aloc, k, x_, gcol, v, Px, Nl, dtype)
-            solved = gcol < k * v
-            s = jnp.matmul(rows, jnp.where(solved[:, None], yv[gcol], 0.0),
-                           precision=lax.Precision.HIGHEST)
-            s = lax.psum(s, AXIS_Y)
             kv = jnp.asarray(k * v, jnp.int32)
             bk = lax.dynamic_slice(b, (kv, i0), (v, nrhs))
-            yk = blas.trsm_left_lower(jnp.tril(diag), bk - s)
+            yk = _solve_block(
+                Aloc, k, yv, gcol < k * v, bk,
+                lambda d, r: blas.trsm_left_lower(jnp.tril(d), r),
+                gcol, v, Px, Py)
             return lax.dynamic_update_slice(yv, yk, (kv, i0))
 
         with jax.named_scope("solve_fwd"):
@@ -984,15 +992,12 @@ def _build_qr_lstsq(geom, mesh_key):
         # ---- back substitution R x = c over R's geometry -------------- #
         def bwd(i, xv):
             k = n - 1 - i
-            rows, diag = _diag_tile_rows(Rloc, k, x_, gcol, v, Px, Nl,
-                                         dtype)
-            ahead = gcol >= (k + 1) * v
-            s = jnp.matmul(rows, jnp.where(ahead[:, None], xv[gcol], 0.0),
-                           precision=lax.Precision.HIGHEST)
-            s = lax.psum(s, AXIS_Y)
             kv = jnp.asarray(k * v, jnp.int32)
             ck = lax.dynamic_slice(cv, (kv, i0), (v, nrhs))
-            xk = blas.trsm_left_upper(jnp.triu(diag), ck - s)
+            xk = _solve_block(
+                Rloc, k, xv, gcol >= (k + 1) * v, ck,
+                lambda d, r: blas.trsm_left_upper(jnp.triu(d), r),
+                gcol, v, Px, Py)
             return lax.dynamic_update_slice(xv, xk, (kv, i0))
 
         xv = lax.fori_loop(0, n, bwd, jnp.zeros((geom.N, nrhs), dtype))

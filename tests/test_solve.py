@@ -511,3 +511,103 @@ def test_solver_utilities_complex():
     np.testing.assert_allclose(logabs, l_ref, rtol=1e-10)
     Ainv = np.asarray(inv_from_lu(LU, perm))
     np.testing.assert_allclose(A @ Ainv, np.eye(N), atol=1e-10)
+
+
+def _row_tile_psum_block(Aloc, k, vec, known, rhs, tri_solve, gcol, v, Px,
+                         Py):
+    """The mesh solves' block step as it was first written: row tile k
+    psummed whole over 'x', its diagonal block scatter-added out of it and
+    psummed over 'y', the (v, nrhs) products psummed over 'y'."""
+    from jax import lax
+
+    x_ = lax.axis_index("x")
+    li = ((k // Px) * v).astype(jnp.int32)
+    part = jnp.where(
+        x_ == k % Px,
+        lax.dynamic_slice(Aloc, (li, jnp.zeros((), jnp.int32)),
+                          (v, Aloc.shape[1])),
+        jnp.zeros((), Aloc.dtype))
+    rows = lax.psum(part, "x")
+    idx = jnp.where((gcol >= k * v) & (gcol < (k + 1) * v), gcol - k * v, v)
+    diag = jnp.zeros((v, v), Aloc.dtype).at[:, idx].add(
+        jnp.where(idx[None, :] < v, rows, 0.0), mode="drop")
+    diag = lax.psum(diag, "y")
+    s = jnp.matmul(rows, jnp.where(known[:, None], vec[gcol], 0.0),
+                   precision=lax.Precision.HIGHEST)
+    s = lax.psum(s, "y")
+    return tri_solve(diag, rhs - s)
+
+
+def _clear_mesh_solve_caches():
+    from conflux_tpu import solvers
+
+    for build in (solvers._build_lu_solve, solvers._build_cholesky_solve,
+                  solvers._build_qr_lstsq):
+        build.cache_clear()
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.complex64],
+                         ids=["f32", "c64"])
+@pytest.mark.parametrize("nrhs", [1, 3])
+@pytest.mark.parametrize("grid", [(1, 1, 1), (2, 1, 1), (1, 2, 1), (2, 2, 1),
+                                  (2, 1, 2), (4, 2, 1)],
+                         ids=lambda g: "x".join(map(str, g)))
+@pytest.mark.parametrize("solver", ["lu", "cholesky", "qr"])
+def test_mesh_solve_block_step_matches_row_tile_psum(monkeypatch, solver,
+                                                     grid, nrhs, dtype):
+    """Each mesh solve's block step (partial products summed over the
+    grid, the diagonal block read in place by its owner) gives the result
+    of the row-tile psum and scatter-added diagonal it replaced: both add
+    only exact zeros to the same products."""
+    import jax
+
+    from conflux_tpu import solvers
+    from conflux_tpu.geometry import CholeskyGeometry, Grid3, LUGeometry
+    from conflux_tpu.parallel.mesh import make_mesh
+
+    N, v = 512, 64
+    g = Grid3(*grid)
+    mesh = make_mesh(g, devices=jax.devices()[: g.P])
+    rng = np.random.default_rng(sum(grid) * 10 + nrhs)
+
+    def rand(*shape):
+        x = rng.uniform(-1, 1, shape)
+        if dtype == np.complex64:
+            x = x + 1j * rng.uniform(-1, 1, shape)
+        return x
+
+    # factors with small off-diagonal entries and a dominant diagonal, so
+    # every substitution stays well scaled
+    F = (rand(N, N) / N + 2 * np.eye(N)).astype(dtype)
+    b = rand(N, nrhs).astype(dtype)
+    if solver == "lu":
+        geom = LUGeometry.create(N, N, v, g)
+        perm = rng.permutation(N).astype(np.int32)
+        sh = jnp.asarray(geom.scatter(F))
+        run = lambda: solvers.lu_solve_distributed(sh, perm, geom, mesh, b)
+    elif solver == "cholesky":
+        geom = CholeskyGeometry.create(N, v, g)
+        sh = jnp.asarray(geom.scatter(np.tril(F)))
+        run = lambda: solvers.cholesky_solve_distributed(sh, geom, mesh, b)
+    else:
+        geom = LUGeometry.create(N, N, v, g)
+        Qs = jnp.asarray(geom.scatter(F))
+        Rs = jnp.asarray(geom.scatter(np.triu(F)))
+        run = lambda: solvers.qr_lstsq_distributed(Qs, Rs, geom, mesh, b)
+
+    _clear_mesh_solve_caches()
+    try:
+        x = np.asarray(run())
+        monkeypatch.setattr(solvers, "_solve_block", _row_tile_psum_block)
+        _clear_mesh_solve_caches()
+        x_old = np.asarray(run())
+    finally:
+        monkeypatch.undo()
+        _clear_mesh_solve_caches()
+    assert x.shape == (N, nrhs) and x.dtype == dtype
+    assert np.all(np.isfinite(x))
+    # bitwise but for the order of the matrix-vector reduction, which
+    # XLA's CPU emitter may change when the dot reads a slice in place:
+    # within 4 ulp of the largest entry
+    tol = 4 * np.finfo(dtype).eps * np.abs(x_old).max()
+    np.testing.assert_allclose(x, x_old, rtol=0, atol=tol)

@@ -10,7 +10,9 @@ these tests exist would make xdist's workers collect different tests.
 """
 
 import os
+import re
 
+import numpy as np
 import pytest
 
 import jax
@@ -185,3 +187,41 @@ def test_mesh_cell_programs_compile_and_fit(topo):
         # per chip: the factors, the program's own, and what it returns
         assert (factors + mem.temp_size_in_bytes + mem.output_size_in_bytes
                 < 12e9), (fn, mem)
+
+
+@pytest.mark.parametrize("n,grid", [(32768, (1, 1, 1)), (65536, (2, 2, 1))],
+                         ids=["1x1x1", "2x2x1"])
+def test_lu_solve_moves_only_block_vectors(topo, n, grid):
+    """The benchmark cells' distributed solves at full size: no block step
+    of `solve_fwd` or `solve_bwd` scatters, every collective in their loops
+    reduces at most a (v, nrhs) block (no (v, Nl) row tile), and the solve
+    makes no temporary copy of the factors."""
+    from conflux_tpu.geometry import Grid3, LUGeometry
+    from conflux_tpu.parallel.mesh import (
+        AXIS_X, AXIS_Y, make_mesh, mesh_cache_key)
+    from conflux_tpu.solvers import _build_lu_solve
+
+    v, g = 1024, Grid3(*grid)
+    geom = LUGeometry.create(n, n, v, g)
+    mesh = make_mesh(g, devices=topo.devices[:g.P])
+    shards = _spec((g.Px, g.Py, geom.Ml, geom.Nl),
+                   NamedSharding(mesh, P(AXIS_X, AXIS_Y, None, None)))
+    rep = NamedSharding(mesh, P())
+    compiled = _build_lu_solve(geom, mesh_cache_key(mesh)).lower(
+        shards, _spec((n,), rep, jnp.int32), _spec((n,), rep)).compile()
+    factors = geom.Ml * geom.Nl * 4
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < factors, mem
+    assert (factors + mem.temp_size_in_bytes + mem.output_size_in_bytes
+            < 12e9), mem
+
+    loop = [ln for ln in compiled.as_text().splitlines()
+            if re.search(r"solve_(fwd|bwd)/while/", ln)]
+    assert loop
+    assert not [ln for ln in loop if " scatter(" in ln]
+    reduces = [ln for ln in loop
+               if re.search(r" all-reduce(-start)?\(", ln)]
+    assert reduces or g.P == 1
+    for ln in reduces:
+        dims = re.search(r"= \(?f32\[([\d,]*)\]", ln).group(1)
+        assert np.prod([int(d) for d in dims.split(",")]) <= v * 1, ln
