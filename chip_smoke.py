@@ -25,7 +25,7 @@ import time
 import numpy as np
 
 # one chip
-LU_N, LU_V = 32768, 1024            # bench.py's headline config
+LU_N, LU_V = 32768, 1024            # the lu_n32768 cell's config
 CHOL_N, CHOL_V = 32768, 1024
 FLEET, FLEET_N, FLEET_V = 256, 1024, 256   # ~1 GiB of resident factors
 PALLAS_SESSIONS, PALLAS_N = 16, 512        # the factor kernels' VMEM limit

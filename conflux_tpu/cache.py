@@ -2,8 +2,8 @@
 
 The at-scale programs cost minutes of compile each (the N=32768 LU is
 about a minute and a half per config on a v5e), so the serve path
-(`conflux_tpu.serve`), the miniapp CLIs, `bench.py` and `chip_smoke.py`
-all call :func:`enable_persistent_cache` at startup: a second process
+(`conflux_tpu.serve`), the miniapp CLIs and `chip_smoke.py` all call
+:func:`enable_persistent_cache` at startup: a second process
 hitting the same (geometry, knobs) config deserializes the executable.
 
 Where the cache lives:

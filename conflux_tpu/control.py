@@ -1292,7 +1292,7 @@ class HostLoadEstimator:
         """Memory-model utilization for one host: owned sessions ×
         the measured bytes/session working set over the host's state
         budget. The :class:`FabricAutoscaler`'s capacity axis, seeded
-        from BENCH_WORKINGSET's bytes/session."""
+        from a measured bytes/session."""
         del host  # symmetry with the rate axis; the model is global
         if host_bytes <= 0:
             return 0.0
@@ -1362,18 +1362,18 @@ class AutoscalePolicy:
     cooldown: seconds after ANY membership action before the next;
         covers the drain/adopt transient a resize itself causes.
     bytes_per_session: capacity-model seed — the measured per-session
-        working set (BENCH_WORKINGSET: ~525 KB/session for the
-        default serve shapes; re-seed from your own artifact).
+        working set (~525 KB/session for the default serve shapes,
+        measured on the CPU; re-seed from your own measurement).
     host_bytes: per-host session-state budget the memory axis fills.
     drain_capacity_per_s: per-host solve-rate capacity for the
         `qos_drain_per_s` axis; 0 disables it (memory axis only).
-        Seeded from the measured bench drain numbers the same way
-        the memory axis rides BENCH_WORKINGSET: BENCH_QOS's bulk
-        overload leg drains ~1348 solves/s and BENCH_ADAPTIVE's
-        burst leg ~1253/s per host at the default serve shapes —
-        the default takes the conservative burst figure, so
-        sustained qos pressure (not just memory) can trigger
-        growth. Re-seed from your own artifact for other shapes.
+        Seeded from measured drain rates the same way as the memory
+        axis: a bulk overload drained ~1348 solves/s and a burst
+        ~1253/s per host at the default serve shapes (CPU
+        measurements) — the default takes the conservative burst
+        figure, so sustained qos pressure (not just memory) can
+        trigger growth. Re-seed from your own measurement for other
+        shapes.
     rebalance_ratio / rebalance_floor / max_rebalance_moves: the
         hot-host skew detector forwarded to `ServeFabric.rebalance`
         every tick (bounded background correction, independent of the
@@ -1416,7 +1416,7 @@ class FabricAutoscaler:
 
     **Utilization model.** Per alive host, utilization is the max of
     two axes: memory (owned sessions × `bytes_per_session` /
-    `host_bytes` — the BENCH_WORKINGSET capacity model) and drain
+    `host_bytes` — the working-set capacity model) and drain
     rate (the per-host `qos_drain_per_s` EMA sum against
     `drain_capacity_per_s`, when configured). Decisions use the
     fleet MEAN over alive hosts.

@@ -2942,9 +2942,11 @@ class ServeEngine:
         `plan.factor` itself rides). Warms the CHECKED programs instead
         when the engine's health policy checks outputs — whatever
         program steady-state traffic will actually ride observes zero
-        compiles (asserted via `plan.trace_counts` in tests and
-        bench_engine). `wait=False` compiles on a background thread (the
-        engine-start pattern) and returns the Thread.
+        compiles (asserted via `plan.trace_counts` in
+        tests/test_engine.py::test_engine_prewarm_zero_compiles_in_steady_state
+        and its checked twin in tests/test_resilience.py). `wait=False`
+        compiles on a background thread (the engine-start pattern) and
+        returns the Thread.
 
         `precisions` warms the §33 per-request tier program families
         next to the native ones: each named tier's solve programs (per

@@ -57,10 +57,10 @@ Two host flavors share one op core (:class:`_HostCore`):
 lockcheck soaks, fault drills) and :class:`ProcessHost` spawns
 ``python -m conflux_tpu.fabric --worker`` wired over an authenticated
 ``multiprocessing.connection`` AF_UNIX pipe (the real fabric; see
-scripts/fabric_drill.py and ``bench_engine.py --fabric``). Checkpoint
-records live on a filesystem shared by front and hosts (same box or
-shared mount) — the front reads a dead host's snapshot directly and
-points survivors at it.
+scripts/fabric_drill.py, whose two-process SIGKILL drill CI runs).
+Checkpoint records live on a filesystem shared by front and hosts
+(same box or shared mount) — the front reads a dead host's snapshot
+directly and points survivors at it.
 
 Fault injection (`resilience.FaultPlan`) covers the fabric control
 plane: 'heartbeat' (delay/crash — a slow or failed probe, the
@@ -1245,9 +1245,9 @@ class ProcessHost(HostHandle):
                           qos=None if qos is None else qos.to_wire())
 
     def echo(self, b, timeout: float | None = None):
-        """RPC-layer microbench op (``bench_engine.py --wire``): the
-        payload round-trips through whichever wire this host runs,
-        engine bypassed — isolates transport cost from solve cost."""
+        """RPC-layer echo op: the payload round-trips through whichever
+        wire this host runs, engine bypassed — isolates transport cost
+        from solve cost (tests/test_wire.py holds its bitwise parity)."""
         w = self._wire
         if w is not None:
             b2 = np.asarray(b)
@@ -1256,15 +1256,13 @@ class ProcessHost(HostHandle):
         return self._call("echo", timeout=timeout, b=np.asarray(b))
 
     def echo_many(self, payloads, timeout: float | None = None):
-        """Pipelined batch echo (``bench_engine.py --wire``): submit
-        EVERY payload before awaiting any reply, so the measured cost
-        is the wire itself, not one round-trip latency per request.
-        On the shm wire the burst goes through
+        """Pipelined batch echo: submit EVERY payload before awaiting
+        any reply, so the cost is the wire itself, not one round-trip
+        latency per request. On the shm wire the burst goes through
         :meth:`WireClient.submit_many` — N payloads, one lock, a
         handful of ``solve_many`` frames — honouring ring
         backpressure with the measured-drain retry hint; on the
-        pickle wire it is one ``Connection.send`` per payload (that
-        per-request serialization IS the baseline being measured).
+        pickle wire it is one ``Connection.send`` per payload.
         Returns the echoed arrays in submission order."""
         arrs = [np.asarray(b) for b in payloads]
         w = self._wire

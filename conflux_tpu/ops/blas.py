@@ -397,7 +397,8 @@ def panel_lu(panel: jax.Array, algo: str | None = None):
     Tile-size ceiling on TPU: every LU call is at least v rows tall (the
     tournament's reduction rounds stack 2v), and XLA's LuDecompositionBlock
     custom call overflows its scoped VMEM at ~16384 rows — so v <= 4096 is
-    the safe regime on TPU (v=1024 measured fastest anyway; see bench.py).
+    the safe regime on TPU (v=1024 measured fastest anyway; the
+    benchmark's LU cells run it, `benchmark/configs/`).
     """
     m, v = panel.shape
     algo = _resolve_panel_algo(

@@ -78,8 +78,8 @@ class MeshPlanUnsupported(ValueError):
     fix is a topology fix — drop the pin or restore on a matching
     host — not a fallback code path. Every raise is counted in
     ``profiler.serve_stats()['health']['mesh_plan_unsupported']``
-    (zero on a healthy mesh trace, asserted by ``bench_engine
-    --mesh``). `surface` names the rejecting surface (e.g.
+    (zero on a healthy mesh trace, asserted throughout
+    tests/test_mesh_lane.py). `surface` names the rejecting surface (e.g.
     'factor_lane', 'factor', 'to_device', 'plan_codec')."""
 
     def __init__(self, msg: str, surface: str = ""):
@@ -398,8 +398,8 @@ def rhs_finite(b2: np.ndarray, sample: int | None = None) -> bool:
 
     `sample=k` checks only the first k elements — the SUBMIT guard's
     mode: at production request sizes an exact per-request pass re-reads
-    every byte a second time and alone eats most of the <5% clean-path
-    overhead budget (BENCH_RESILIENCE.json). The sampled check still
+    every byte a second time and alone eats most of a <5% clean-path
+    overhead budget (measured on the CPU). The sampled check still
     rejects wholesale-poisoned requests synchronously; anything that
     slips it is caught EXACTLY by the per-batch staging guard (one
     amortized summation of the coalesced buffer, culprits isolated to

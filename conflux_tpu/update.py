@@ -206,8 +206,8 @@ def health_spot_check(w, wA, x, b, Up=None, Vp=None):
           Freivalds-style through the precomputed probe row wA = w^T A0
           (:func:`probe_row`): w.b0 - wA.x0 costs two O(N) dots where
           the true residual matvec costs O(N^2) — which is comparable to
-          the solve itself, and the clean-path overhead gate (<5%,
-          BENCH_RESILIENCE.json) forbids that (XLA CPU also runs skinny
+          the solve itself, and a <5% clean-path overhead budget
+          forbids that (XLA CPU also runs skinny
           batched matvecs far off peak, the §17 trsm lesson). With the
           Rademacher w the projection estimates ||r||/||b|| at the same
           relative scale; systemic garbage (factor corruption, an

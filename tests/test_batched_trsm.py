@@ -330,7 +330,10 @@ def test_gang_serves_blocked_plan_clean_drifted_checked():
     # drifted + checked window
     from conflux_tpu.resilience import health_stats
 
-    esc0 = health_stats().get("escalations", 0)
+    ladder = ("output_failures", "gang_unhealthy_slots",
+              "survivor_redispatches", "refactor_escalations",
+              "refine_escalations", "unhealthy")
+    h0 = health_stats()
     U = (0.01 * rng.standard_normal((N, 2))).astype(np.float32)
     Vv = (0.01 * rng.standard_normal((N, 2))).astype(np.float32)
     fleet[0].update(U, Vv)
@@ -348,8 +351,10 @@ def test_gang_serves_blocked_plan_clean_drifted_checked():
     assert stH["gang_batches"] >= 1
     for reason in ("upd_pending", "checked", "mesh"):
         assert stH["stack_exclusions"][reason] == 0
-    # the fused verdicts passed clean: no escalation ladder ran
-    assert health_stats().get("escalations", 0) == esc0
+    # the fused verdicts passed clean: no slot failed, no ladder ran
+    h1 = health_stats()
+    assert {k: h1.get(k, 0) - h0.get(k, 0) for k in ladder} \
+        == dict.fromkeys(ladder, 0)
 
 
 def test_gang_blocked_zero_compiles_after_prewarm():

@@ -178,37 +178,6 @@ def test_qr_miniapp_rejects_wide(capsys):
         qr_miniapp.main(["-M", "16", "--cols", "32"])
 
 
-def test_bench_cli_smoke():
-    """The driver's bench entry runs end-to-end off-chip via the smoke
-    overrides (-N, --platform cpu) in every mode — the one-shot chip
-    queue must exercise no untested code path."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    for mode_args in (["--mode", "f32"], ["--mode", "mxp", "--ir", "gmres",
-                                          "--refine", "2"]):
-        out = subprocess.run(
-            [sys.executable, os.path.join(root, "bench.py"),
-             "--platform", "cpu", "-N", "1024", *mode_args],
-            capture_output=True, text=True, timeout=600, cwd=root, env=env,
-        )
-        assert out.returncode == 0, out.stderr[-2000:]
-        line = [l for l in out.stdout.splitlines() if l.startswith("{")][-1]
-        rec = json.loads(line)
-        assert rec["unit"] == "GFLOP/s" and rec["value"] > 0
-        assert rec["residual"] < (1e-5 if mode_args[1] == "f32" else 1e-6)
-    bad = subprocess.run(
-        [sys.executable, os.path.join(root, "bench.py"),
-         "--platform", "cpu", "-N", "1000"],
-        capture_output=True, text=True, timeout=120, cwd=root, env=env,
-    )
-    assert bad.returncode != 0 and "multiple" in bad.stderr
-
-
 def test_cholesky_miniapp_refine(capsys):
     from conflux_tpu.cli import cholesky_miniapp
 

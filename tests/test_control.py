@@ -95,9 +95,17 @@ def test_controller_lifecycle_and_stats():
             if ctl.stats()["ticks"] >= 2:
                 break
             deadline.wait(0.01)
+        # clean traffic while the controller ticks: every future
+        # answers, and answers the direct solve
+        bs = [np.full((N, 1), float(i + 1), np.float32) for i in range(16)]
+        futs = [eng.submit(s, bi) for bi in bs]
+        for f, bi in zip(futs, bs):
+            np.testing.assert_array_equal(np.asarray(f.result(timeout=60)),
+                                          np.asarray(s.solve(bi)))
         st = eng.stats()
         assert st["controller"]["ticks"] >= 2
         assert st["controller"]["errors"] == 0
+        assert st["failed"] == 0
         assert st["knobs"]["max_batch_delay"] == eng.max_batch_delay
     finally:
         eng.close(timeout=60)

@@ -443,10 +443,14 @@ def test_rebalance_bounded_and_no_reshuffle(tmp_path):
 # --------------------------------------------------------------------------- #
 
 
-def test_replica_repoint_failover_bitwise(tmp_path):
+@pytest.mark.parametrize("dead_disk", [False, True],
+                         ids=["disk_lives", "disk_dies"])
+def test_replica_repoint_failover_bitwise(tmp_path, dead_disk):
     """K=2: kill a host and its sessions re-point to standbys that
     adopt from LOCAL replica records — zero snapshot restores, zero
-    lost, bitwise answers."""
+    lost, bitwise answers that meet the f64 oracle. `disk_dies`: the
+    host's checkpoint directory dies with it (host-local disk), and
+    the re-point needs nothing from it."""
     fab = _fab(tmp_path, n=3, replicas=2)
     fab.start()
     try:
@@ -456,12 +460,19 @@ def test_replica_repoint_failover_bitwise(tmp_path):
         assert fab.stats()["replicated_sessions"] == len(sids)
         before = {s: np.asarray(fab.solve(s, _rhs(i)))
                   for i, s in enumerate(sids)}
+        for i, s in enumerate(sids):
+            x64 = np.linalg.solve(_mk(i).astype(np.float64),
+                                  _rhs(i).astype(np.float64))
+            assert np.max(np.abs(before[s] - x64)) < 1e-3, s
         census = fab.owner_census()
         victim = max(census, key=lambda h: (census[h], h))
         owned = census[victim]
         s0 = _counter("fabric_snapshot_restores")
         p0 = _counter("fabric_replica_repoints")
         fab._hosts[victim].kill()
+        if dead_disk:
+            ck = fab._hosts[victim].ckpt_dir
+            os.rename(ck, ck + ".dead")
         _wait_dead(fab, victim)
         rec = _wait_recovery(fab, victim)
         assert rec["lost"] == 0
@@ -667,7 +678,8 @@ def test_autoscaler_scale_in_drains_least_loaded(tmp_path):
 
 def test_autoscaler_full_wave_round_trip(tmp_path):
     """A load wave out and back: grow under pressure, shrink when it
-    recedes, sessions bitwise across BOTH membership changes."""
+    recedes, sessions bitwise across BOTH membership changes, nothing
+    lost and the census exact."""
     fab = _fab(tmp_path, n=2)
     fab.start()
     try:
@@ -687,5 +699,11 @@ def test_autoscaler_full_wave_round_trip(tmp_path):
                               np.asarray(fab.solve("s7", _rhs(7))))
         log = auto.stats()["decisions_log"]
         assert [e["action"] for e in log] == ["scale_out", "scale_in"]
+        st = fab.stats()
+        assert st["lost_sessions"] == 0
+        assert st["admitted_sessions"] == 8
+        assert (st["admitted_sessions"]
+                == st["sessions"] + st["lost_sessions"]
+                + st["closed_sessions"])
     finally:
         fab.close()

@@ -21,7 +21,7 @@ checkpoint-backed fail-over and live session migration.
 
 All tests run the single-process LocalHost fabric (deterministic,
 lockcheck-able); the two-process ProcessHost path is exercised by
-scripts/fabric_drill.py (CI job) and `bench_engine.py --fabric`.
+scripts/fabric_drill.py (CI job).
 """
 
 import os
@@ -80,6 +80,17 @@ def _wait_dead(fab, hid, timeout=20.0):
     raise AssertionError(f"host {hid} never declared dead")
 
 
+def _wait_recovery(fab, hid, timeout=20.0):
+    """The recovery record lands after the dead flip; poll for it."""
+    t0 = time.perf_counter()
+    while time.perf_counter() - t0 < timeout:
+        for rec in reversed(fab.stats()["recoveries"]):
+            if rec["host"] == hid:
+                return rec
+        time.sleep(0.02)
+    raise AssertionError(f"no recovery record for {hid}")
+
+
 # --------------------------------------------------------------------------- #
 # routing
 # --------------------------------------------------------------------------- #
@@ -136,14 +147,22 @@ def test_kill_one_host_failover_bitwise_and_bounded(tmp_path):
     """The tentpole drill: kill the host owning sessions; detection +
     fail-over re-home its fleet on survivors from the last checkpoint;
     every session (including revived ones) solves BITWISE as before;
-    recovery time is measured and bounded."""
+    recovery time is measured and bounded; the census is conserved.
+    Before the kill, every fabric answer is the bare session's bits
+    and within reach of the f64 oracle."""
     with _fab(tmp_path) as fab:
         ref, rhs = {}, {}
         for i in range(9):
             sid = f"drill-{i}"
-            fab.open(sid, _plan(), _mk(10 + i))
+            A = _mk(10 + i)
+            fab.open(sid, _plan(), A)
             rhs[sid] = _rhs(i, w=2)
             ref[sid] = np.asarray(fab.solve(sid, rhs[sid]))
+            bare = _plan().factor(A).solve(rhs[sid])
+            assert np.array_equal(ref[sid], np.asarray(bare)), sid
+            x64 = np.linalg.solve(A.astype(np.float64),
+                                  rhs[sid].astype(np.float64))
+            assert np.max(np.abs(ref[sid] - x64)) < 1e-3, sid
         victim = fab.owner_of("drill-0")
         moved = [s for s in ref if fab.owner_of(s) == victim]
         stay = {s: fab.owner_of(s) for s in ref
@@ -152,11 +171,12 @@ def test_kill_one_host_failover_bitwise_and_bounded(tmp_path):
         fab._hosts[victim].kill()
         _wait_dead(fab, victim)
         # bounded recovery: the fail-over measured itself
-        rec = fab.stats()["recoveries"]
-        assert rec and rec[-1]["host"] == victim
-        assert rec[-1]["adopted"] == len(moved)
-        assert rec[-1]["lost"] == 0
-        assert rec[-1]["seconds"] < 30.0
+        rec = _wait_recovery(fab, victim)
+        assert rec["adopted"] == len(moved)
+        assert rec["lost"] == 0
+        assert rec["seconds"] < 30.0
+        st = fab.stats()
+        assert st["sessions"] == 9 and st["lost_sessions"] == 0
         # every session answers bitwise; survivors never moved
         for sid in ref:
             assert np.array_equal(
@@ -185,22 +205,35 @@ def test_dead_host_requests_fail_structured_not_hang(tmp_path):
         assert resilience.health_stats()["host_unavailable"] >= 1
 
 
-def test_never_checkpointed_session_reported_lost(tmp_path):
-    """durable_open off + no background checkpointing: a killed host's
-    sessions are unrecoverable — the fabric says so (structured, with
-    the reason), conserves the count in stats, and lets the sid be
-    reopened."""
-    with _fab(tmp_path, n=2, durable_open=False) as fab:
-        fab.open("gone", _plan(), _mk(4))
+@pytest.mark.parametrize("how", ["never_checkpointed", "disk_died"])
+def test_never_checkpointed_session_reported_lost(tmp_path, how):
+    """A killed host's sessions are unrecoverable when they never
+    reached a checkpoint (durable_open off, no background
+    checkpointing) or when, with one replica, the checkpoint directory
+    died with the host (host-local disk): the fabric says so
+    (structured, with the reason), conserves the count in stats, and
+    lets the sid be reopened — re-factored, answering the f64
+    oracle."""
+    kw = {"durable_open": False} if how == "never_checkpointed" else {}
+    with _fab(tmp_path, n=2, **kw) as fab:
+        A = _mk(4)
+        fab.open("gone", _plan(), A)
         victim = fab.owner_of("gone")
         fab._hosts[victim].kill()
+        if how == "disk_died":
+            ck = fab._hosts[victim].ckpt_dir
+            os.rename(ck, ck + ".dead")
         _wait_dead(fab, victim)
+        assert _wait_recovery(fab, victim)["lost"] == 1
         assert fab.stats()["lost_sessions"] == 1
         with pytest.raises(HostUnavailable, match="lost"):
             fab.solve("gone", _rhs(4))
         # a lost sid may be reopened (fresh state, back in service)
-        fab.open("gone", _plan(), _mk(4))
-        fab.solve("gone", _rhs(4))
+        fab.open("gone", _plan(), A)
+        x = np.asarray(fab.solve("gone", _rhs(4)))
+        x64 = np.linalg.solve(A.astype(np.float64),
+                              _rhs(4).astype(np.float64))
+        assert np.max(np.abs(x - x64)) < 1e-3
         assert fab.stats()["lost_sessions"] == 0
 
 
@@ -394,9 +427,11 @@ def test_heartbeat_hysteresis_suspect_then_recover(tmp_path):
 
 def test_host_kill_fault_site_drives_failover(tmp_path):
     """The seeded host_kill fault kills a whole host from inside the
-    heartbeat loop; detection + fail-over then run end-to-end."""
+    heartbeat loop; detection + fail-over then run end-to-end. The
+    fault is armed once the sessions are open, so the kill cannot land
+    mid-admission."""
     plan = FaultPlan([FaultSpec(site="host_kill", kind="kill",
-                                count=1)])
+                                count=0)])
     with _fab(tmp_path, fault_plan=plan, durable_open=True) as fab:
         ref, rhs = {}, {}
         for i in range(4):
@@ -404,6 +439,7 @@ def test_host_kill_fault_site_drives_failover(tmp_path):
             fab.open(sid, _plan(), _mk(20 + i))
             rhs[sid] = _rhs(20 + i)
             ref[sid] = np.asarray(fab.solve(sid, rhs[sid]))
+        plan.specs[0].count = 1  # arm: the next heartbeat kills a host
         deadline = time.perf_counter() + 20.0
         while time.perf_counter() < deadline:
             if any(fab.host_state(h) == "dead"
@@ -413,6 +449,7 @@ def test_host_kill_fault_site_drives_failover(tmp_path):
         dead = [h for h in sorted(fab._hosts)
                 if fab.host_state(h) == "dead"]
         assert len(dead) == 1
+        assert _wait_recovery(fab, dead[0])["lost"] == 0
         for sid in ref:
             assert np.array_equal(
                 np.asarray(fab.solve(sid, rhs[sid])), ref[sid]), sid

@@ -283,6 +283,10 @@ def test_prewarm_warms_every_lane_and_dedupes():
         st = eng.stats()
         active = [ln for ln in st["lanes"] if ln["batches"]]
         assert len(active) == 3, "every lane dispatched"
+        # uniform round-robin load: each lane served its own sessions'
+        # requests, no more and no fewer
+        assert [ln["coalesced_requests"] for ln in st["lanes"]] \
+            == [4, 4, 4]
 
 
 # --------------------------------------------------------------------- #

@@ -29,7 +29,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from conflux_tpu import serve
+from conflux_tpu import profiler, serve
 from conflux_tpu.batched import (
     grow_stack_tree,
     stack_trees,
@@ -126,27 +126,34 @@ def test_gang_matches_direct_and_bitwise_within_bucket():
     np.testing.assert_array_equal(res[3], ref)
 
 
-def test_gang_resident_steady_state_no_restack_no_compile():
+@pytest.mark.parametrize("health", [None, HealthPolicy()],
+                         ids=["plain", "checked"])
+def test_gang_resident_steady_state_no_restack_no_compile(health):
     """Second and later windows re-sync nothing (version counters
-    unchanged), rebuild nothing, and compile nothing."""
+    unchanged), rebuild nothing, and compile nothing — no plan trace
+    and no XLA compile, on the checked (fused per-slot verdict) gang
+    too."""
     serve.clear_plans()
     plan, fleet, _A = _fleet(4, seed=21)
     bs = _rhs(4, seed=22)
     eng = ServeEngine(max_batch_delay=0.05, stack_sessions=True,
-                      max_stack=4)
+                      max_stack=4, health=health)
     try:
         for f in [eng.submit(s, b) for s, b in zip(fleet, bs)]:
             f.result(60)
         g = _gang_of(eng, plan)
         st0 = g.stats()
         traces0 = dict(plan.trace_counts)
+        compiles0 = profiler.compile_count()
         for _ in range(3):
             for f in [eng.submit(s, b) for s, b in zip(fleet, bs)]:
                 f.result(60)
         st1 = g.stats()
+        compiles = profiler.compile_count() - compiles0
     finally:
         eng.close(timeout=120)
     assert plan.trace_counts == traces0, "steady-state window compiled"
+    assert compiles == 0, f"{compiles} XLA compiles in the steady state"
     assert st1["adopts"] == st0["adopts"]
     assert st1["rebuilds"] == st0["rebuilds"]
     assert st1["refreshes"] == st0["refreshes"] == 0
@@ -180,6 +187,7 @@ def test_gang_drifted_and_checked_sessions_stack():
     excl = st["stack_exclusions"]
     assert excl["upd_pending"] == 0, "drifted sessions must stack now"
     assert excl["checked"] == 0, "checked sessions must stack now"
+    assert excl["mesh"] == 0
     assert st["gang_batches"] == 1, "the whole window rode one dispatch"
     g = _gang_of(eng, plan)
     assert g.stats()["rank_bucket"] == 4  # rank_bucket(3)

@@ -75,7 +75,7 @@ def test_lu_distributed_chunked_election():
 
 
 def test_lu_distributed_bench_ratios():
-    """Structural pin of the headline bench config (bench.py: N=32768,
+    """Structural pin of the lu_n32768 benchmark cell's config (N=32768,
     v=1024, chunk=8192 on 1x1x1) at 1/128 scale: the same N/v = 32
     supersteps and Ml/chunk = 4 nomination chunks, through the same
     single-device mesh program. Small-N grid tests can't see bugs that
@@ -86,7 +86,8 @@ def test_lu_distributed_bench_ratios():
     LU, perm, _ = lu_distributed_host(A, Grid3(1, 1, 1), v, panel_chunk=64)
     assert sorted(perm.tolist()) == list(range(N))
     res = lu_residual(A, LU[perm], perm)
-    assert res < residual_bound(N, np.float32), res
+    # the f32 factor's 1e-5 bar, tighter than the generic bound here
+    assert res < min(residual_bound(N, np.float32), 1e-5), res
 
 
 def test_lu_distributed_election_height_bound():

@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from conflux_tpu import serve
+from conflux_tpu import profiler, serve
 from conflux_tpu.batched import cholesky_factor_batched, lu_factor_batched
 from conflux_tpu.engine import ServeEngine
 from conflux_tpu.ops import blas
@@ -419,11 +419,14 @@ def test_pallas_bucket_programs_trace_once():
     pall._stacked_factor_fn(2)(jnp.asarray(A))
     pall._factor_health_fn(2)(jnp.asarray(A))
     snapshot = dict(pall.trace_counts)
+    compiles0 = profiler.compile_count()
     for _ in range(3):
         pall._stacked_factor_fn(2)(jnp.asarray(A))
         pall._factor_health_fn(2)(jnp.asarray(A))
     assert dict(pall.trace_counts) == snapshot, \
         "steady-state pallas factor buckets traced a program"
+    assert profiler.compile_count() == compiles0, \
+        "steady-state pallas factor buckets compiled"
 
 
 def test_ineligible_keys_fall_back_to_xla_body():

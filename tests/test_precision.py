@@ -291,6 +291,22 @@ def test_engine_precision_routing_and_zero_compiles():
             eng.submit(s, b, precision="fp8")
     finally:
         eng.close()
+    # a checked prewarm of "auto" warms every rung's CHECKED program, an
+    # f32-opened session's own included; a healthy system never climbs
+    eng = ServeEngine(max_batch_delay=0.001,
+                      health=resilience.HealthPolicy())
+    try:
+        eng.prewarm(s2, widths=(1,), precisions=("auto",))
+    finally:
+        eng.close()
+    sf = plan.factor(A, precision="f32")
+    t0 = dict(plan.trace_counts)
+    xa, _va = s2.solve_checked(b, precision="auto")
+    xf, _vf = sf.solve_checked(b, precision="f32")
+    assert {k: v - t0.get(k, 0) for k, v in plan.trace_counts.items()
+            if v - t0.get(k, 0)} == {}, "a ladder rung compiled"
+    assert _resid(A, xa, b) < 1e-2 and _resid(A, xf, b) < 1e-5
+    assert s2.precision_escalations == sf.precision_escalations == 0
 
 
 def test_auto_ladder_escalates_and_sticks():

@@ -178,10 +178,11 @@ def test_ledger_release_never_goes_negative():
 
 def test_qos_none_engine_bitwise_identical():
     serve.clear_plans()
-    _, s = _session(seed=11)
+    plan, s = _session(seed=11)
     b = jnp.asarray(np.ones(N, np.float32))
     with ServeEngine(max_batch_delay=0.0) as eng:
         plain = np.asarray(eng.solve(s, b))
+        traces0 = dict(plan.trace_counts)
         # no classified traffic ever: no state, no dict keys
         assert eng._qos is None
         assert "qos" not in eng.counters()
@@ -193,6 +194,8 @@ def test_qos_none_engine_bitwise_identical():
         assert eng._qos is not None
         assert "qos" in eng.counters()
     np.testing.assert_array_equal(plain, tagged)
+    # classified traffic rides the very same programs: nothing compiled
+    assert plan.trace_counts == traces0
 
 
 def test_qos_type_validation_on_submit():

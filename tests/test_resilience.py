@@ -379,18 +379,31 @@ def test_close_timeout_reports_wedged_thread_and_fails_pending():
 
 
 def test_guarded_clean_path_zero_compiles_after_prewarm():
+    """Clean traffic through the full HealthPolicy: zero compiles after
+    prewarm, the guards stay SILENT (a false positive is an escalation:
+    right answers, wasted device work), and the guarded answers are the
+    direct solves'."""
     session = _session(53)
     plan = session.plan
+    reqs = [_rhs(50 + i, 1 + i % 2) for i in range(12)]
+    h0 = resilience.health_stats()
     with ServeEngine(max_batch_delay=0.02, max_coalesce_width=4,
                      health=HealthPolicy()) as eng:
         eng.prewarm(session, widths=(1, 2, 4))
         snapshot = dict(plan.trace_counts)
-        futs = [eng.submit(session, _rhs(50 + i, 1 + i % 2))
-                for i in range(12)]
-        for f in futs:
-            f.result(timeout=60)
+        futs = [eng.submit(session, b) for b in reqs]
+        xs = [np.asarray(f.result(timeout=60)) for f in futs]
         assert plan.trace_counts == snapshot, \
             "guarded steady-state traffic compiled after prewarm"
+    trips = {k: v for k, v in _delta(h0, resilience.health_stats()).items()
+             if k in ("output_failures", "staging_isolations",
+                      "rhs_rejects", "unhealthy", "refactor_escalations",
+                      "refine_escalations", "survivor_redispatches")}
+    assert not trips, f"guards tripped on clean traffic: {trips}"
+    for i, (x, b) in enumerate(zip(xs, reqs)):
+        np.testing.assert_allclose(x, np.asarray(session.solve(b)),
+                                   rtol=1e-5, atol=1e-6,
+                                   err_msg=f"request {i}")
 
 
 def test_thread_hammer_every_future_resolves():

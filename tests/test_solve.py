@@ -248,19 +248,29 @@ def test_fgmres_beats_classic_ir_on_bf16_factors():
     assert _relerr(A, np.asarray(xg, np.float64), b) < 1e-6
 
 
-def test_solve_distributed_gmres_ir():
-    """ir='gmres' end-to-end on the mesh: bf16 factors + FGMRES reach the
-    1e-6 bar where refine= (classic) cannot on an ill-enough matrix."""
+@pytest.mark.parametrize("low", ["bf16_factors", "precision_high"])
+def test_solve_distributed_gmres_ir(low):
+    """ir='gmres' end-to-end on the mesh: a low-precision factor + FGMRES
+    reach the bar where refine= (classic) cannot on an ill-enough matrix.
+    The factor is bf16 storage, or f32 storage with bf16x3 trailing
+    GEMMs (`precision=HIGH`, the HPL-MxP mode), held to the 1e-6
+    solve bar."""
+    from jax import lax
+
     from conflux_tpu.geometry import Grid3
     from conflux_tpu.solvers import solve_distributed
 
     N = 128
     A = make_test_matrix(N, N, seed=18, dtype=np.float32)  # no diag boost
     b = np.ones(N, np.float32)
+    if low == "bf16_factors":
+        kw, bar = {"factor_dtype": jnp.bfloat16}, 1e-8
+    else:
+        kw, bar = {"precision": lax.Precision.HIGH}, 1e-6
     x = solve_distributed(jnp.asarray(A), jnp.asarray(b), grid=Grid3(2, 1, 1),
-                          v=16, factor_dtype=jnp.bfloat16, ir="gmres",
-                          tol=1e-8, restart=16, max_restarts=8)
-    assert _relerr(A, np.asarray(x, np.float64), b) < 1e-8
+                          v=16, ir="gmres", tol=1e-8, restart=16,
+                          max_restarts=8, **kw)
+    assert _relerr(A, np.asarray(x, np.float64), b) < bar
 
 
 def test_solve_distributed_rejects_padding():

@@ -288,18 +288,28 @@ def test_lru_eviction_count_cap():
     assert rs.stats()["resident_sessions"] <= 2
 
 
-def test_byte_cap_bounds_high_water():
+@pytest.mark.parametrize("precision", [None, "bf16_ir"],
+                         ids=["native", "bf16_ir"])
+def test_byte_cap_bounds_high_water(precision):
+    """Churn twice through a fleet of twice the byte cap: the high-water
+    stays under the cap (for precision-tier sessions too, whose bf16
+    factors are smaller), the churn compiles nothing, and every answer
+    after spill/revive is the untiered session's, bitwise."""
     plan = _plan()
-    fleet = _fleet(plan, 4, seed=10)
-    per = fleet[0][0].nbytes
+    rng = np.random.default_rng(10)
+    fleet = [plan.factor(jnp.asarray(_mk(rng)), precision=precision)
+             for _ in range(4)]
+    b = rng.standard_normal((N,)).astype(np.float32)
+    want = [np.asarray(s.solve(b)) for s in fleet]  # before any spill
+    traces0 = dict(plan.trace_counts)
+    per = fleet[0].nbytes
     cap = 2 * per
     rs = ResidentSet(max_bytes=cap, evict_batch=1)
-    for s, _ in fleet:
+    for s in fleet:
         rs.adopt(s)
-    rng = np.random.default_rng(10)
-    b = rng.standard_normal((N,)).astype(np.float32)
-    for s, _ in fleet * 2:  # churn through the fleet twice
-        s.solve(b)
+    for s, x in list(zip(fleet, want)) * 2:  # churn through the fleet
+        assert np.array_equal(np.asarray(s.solve(b)), x)
+    assert plan.trace_counts == traces0, "spill/revive churn compiled"
     st = rs.stats()
     assert st["device_bytes"] <= cap
     assert st["device_bytes_high_water"] <= cap, st

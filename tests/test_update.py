@@ -202,6 +202,7 @@ def test_update_zero_recompiles_per_bucket():
         session.solve(jnp.asarray(
             rng.standard_normal(N).astype(np.float32)))
     assert plan.trace_counts == t, "same-bucket drift traffic recompiled"
+    assert session.refactors == 0, "the drift policy refactored"
     # a second session on the same plan shares every compiled program
     s2 = plan.factor(jnp.asarray(A))
     s2.update(jnp.asarray(U), jnp.asarray(Vm)).solve(jnp.asarray(b))

@@ -26,7 +26,7 @@ payload rings + batched control plane.
   with zero pending-entry leaks.
 
 The cross-process path (ProcessHost + worker) is exercised by
-scripts/fabric_drill.py and ``bench_engine.py --wire`` (CI jobs);
+scripts/fabric_drill.py (phase 5, the torn-reply drill; CI job);
 the ProcessHost timeout-composition regression lives in
 tests/test_fabric.py.
 """
@@ -259,7 +259,7 @@ def test_loopback_echo_parity_and_batching():
 def test_loopback_engine_parity_bitwise():
     """Solves routed through the shm wire into a REAL ServeEngine are
     BITWISE identical to direct submits — zero-copy staging does not
-    perturb a single bit."""
+    perturb a single bit — and answer the f64 oracle."""
     import jax.numpy as jnp
 
     from conflux_tpu import serve
@@ -281,6 +281,9 @@ def test_loopback_engine_parity_bitwise():
                 ref = np.asarray(eng.submit(s, b).result(timeout=30))
                 got = w.solve("sid", b).result(timeout=30)
                 assert np.array_equal(got, ref)
+                x64 = np.linalg.solve(A.astype(np.float64),
+                                      b.astype(np.float64))
+                assert np.max(np.abs(got - x64)) < 1e-3
         finally:
             w.close()
 
